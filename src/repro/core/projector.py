@@ -26,6 +26,8 @@ KIND_PROJECT = "project"  # last-two-axes matrix (possibly stacked) -> low-rank
 KIND_CONV = "conv"  # (O, I, K1, K2) conv kernel -> Tucker-2 (core/conv.py)
 KIND_DENSE = "dense"  # full-rank Adam/Adafactor
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 class ProjSpec(NamedTuple):
     """Static per-leaf projection decision."""
@@ -160,13 +162,14 @@ def from_canonical(g: jnp.ndarray, spec: ProjSpec) -> jnp.ndarray:
 
 
 def project(g_canon: jnp.ndarray, p: jnp.ndarray) -> jnp.ndarray:
-    """``G_proj = G P`` over the last two axes: (...,m,n)@(...,n,r)->(...,m,r)."""
-    return jnp.einsum("...mn,...nr->...mr", g_canon, p)
+    """``G_proj = G P`` over the last two axes: (...,m,n)@(...,n,r)->(...,m,r),
+    at fp32 precision like the fused kernels."""
+    return jnp.einsum("...mn,...nr->...mr", g_canon, p, precision=HIGHEST)
 
 
 def backproject(u_proj: jnp.ndarray, p: jnp.ndarray) -> jnp.ndarray:
     """``ΔW = ΔW_proj Pᵀ``: (...,m,r)@(...,n,r)ᵀ -> (...,m,n)."""
-    return jnp.einsum("...mr,...nr->...mn", u_proj, p)
+    return jnp.einsum("...mr,...nr->...mn", u_proj, p, precision=HIGHEST)
 
 
 def reconstruct(g_canon: jnp.ndarray, p: jnp.ndarray) -> jnp.ndarray:

@@ -90,7 +90,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import conv as conv_mod
 from repro.core import projector, recalibrate
 from repro.core import stacked_state
@@ -556,7 +555,7 @@ def make_compressed_train_step(model, cfg: ProjectedAdamConfig, mesh,
     pspec = P()  # replicated over pod (manual axis)
     in_specs = (pspec, pspec, pspec, P(axis))
     out_specs = (pspec, pspec, pspec)
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         per_pod, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False, axis_names={axis},
     )

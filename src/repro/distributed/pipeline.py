@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 
 def split_stage_params(stacked_params: Any, n_stages: int):
@@ -99,7 +98,7 @@ def pipeline_apply(
     # same schedule.
     in_specs = (P(axis), P())
     out_specs = P()
-    return compat.shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )(stage_params, x)

@@ -10,7 +10,8 @@ Every ParamDef carries logical axis names; ``spec_for_axes`` maps them to
 mesh axes, dropping any axis that does not divide evenly (safe fallback to
 replication — e.g. the 8-expert dim on a 16-way axis stays local, DESIGN.md
 §4). Activation/cache constraints are applied only when an ambient mesh
-exists, so the same model code runs unsharded on CPU tests.
+is set (``jax.set_mesh``), so the same model code runs unsharded on
+CPU tests.
 """
 from __future__ import annotations
 
@@ -18,7 +19,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.models.layers import ParamDef, is_param_def
 
@@ -56,23 +57,25 @@ def mesh_axis_size(mesh, name: str) -> int:
 
 
 def current_mesh():
-    """The ambient mesh from `with mesh:` (None on unsharded CPU tests)."""
-    try:
-        from jax._src import mesh as mesh_lib
+    """The ambient mesh entered with ``jax.set_mesh`` (None when unsharded).
 
-        env = mesh_lib.thread_resources.env
-        m = env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:  # pragma: no cover
-            return m
-    except Exception:
-        pass
-    return None
+    Inside a ``jax.shard_map`` body this is the abstract mesh, with the
+    body's manual axes typed ``Manual``. Meshes with ``Explicit`` axes are
+    rejected here, before a spec deep in the model fails on them: the
+    model's constraints are written for ``Auto`` axes
+    (``launch/mesh.py``)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return None
+    explicit = [a for a, t in zip(mesh.axis_names, mesh.axis_types)
+                if t == AxisType.Explicit]
+    if explicit:
+        raise ValueError(
+            f"mesh axes {explicit} are Explicit; this program shards with "
+            "Auto axes — build the mesh with repro.launch.mesh (axis_types="
+            "AxisType.Auto)"
+        )
+    return mesh
 
 
 def spec_for_axes(axes: Sequence[Optional[str]], shape: Sequence[int], mesh,
@@ -132,26 +135,8 @@ def batch_axes(mesh) -> Tuple[str, ...]:
 
 def _nonmanual_axes(mesh) -> set:
     """Axes usable in sharding constraints (drops shard_map-manual axes)."""
-    from repro import compat
-
-    manual = compat.manual_axes_in_scope()
-    if manual:
-        if not hasattr(jax, "shard_map"):
-            # jax<=0.4: XLA's partitioner aborts on constraints inside a
-            # partially-manual region (IsManualSubgroup check) — emit none.
-            return set()
-        return set(mesh.axis_names) - set(manual)
-    try:
-        abstract = jax.sharding.get_abstract_mesh()
-        if abstract is not None and not abstract.empty:
-            types = dict(zip(abstract.axis_names, abstract.axis_types))
-            return {
-                a for a in abstract.axis_names
-                if "manual" not in str(types[a]).lower()
-            }
-    except Exception:
-        pass
-    return set(mesh.axis_names)
+    return {a for a, t in zip(mesh.axis_names, mesh.axis_types)
+            if t != AxisType.Manual}
 
 
 def constrain(x, logical: Sequence[Optional[str]]):
@@ -187,17 +172,7 @@ def constrain(x, logical: Sequence[Optional[str]]):
                 axes.append(None)
         else:
             axes.append(None)
-    if not used and not hasattr(jax, "shard_map"):
-        from repro import compat
-
-        if compat.manual_axes_in_scope():
-            # jax<=0.4 inside a shard_map body: even a fully-replicated
-            # constraint aborts XLA's partitioner (IsManualSubgroup check).
-            # Elsewhere the replicated constraint is kept — it pins layout.
-            return x
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(*axes))
-    )
+    return jax.lax.with_sharding_constraint(x, P(*axes))
 
 
 def batch_specs(batch_tree, mesh, seq_shard: bool = False):

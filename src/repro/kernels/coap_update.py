@@ -15,10 +15,11 @@ bm=512) that is a ~1.9× HBM-traffic reduction on the optimizer step
 (measured against cost_analysis in EXPERIMENTS.md §Perf).
 
 Tiling: grid (m/bm, n/bn), n innermost ('arbitrary') for the reduction;
-blocks bm=512, bn=512 keep the working set
-(G 1MB + P 1MB + acc bm·r ≤ 2MB + M/V/out tiles 3·bm·r) under 16MB VMEM for
-r ≤ 1024, with all MXU dims 128-aligned. The wrapper pads ragged shapes and
-vmaps over leading (layer/expert) stack axes.
+blocks start at bm=512, bn=512, with all MXU dims 128-aligned. For the
+back-projection kernel ``plan_two_phase_tiles`` shrinks them until the
+estimated scoped VMEM (``bp_vmem_bytes``) fits the 16 MiB limit: at r=512
+with fp32 G that is (512, 128). The wrapper pads ragged shapes and vmaps
+over leading (layer/expert) stack axes.
 
 bf16 gradient streaming: G blocks are DMA'd in the caller's dtype and
 upcast to fp32 in VMEM (the ``astype`` inside the body), so bf16 training
@@ -45,16 +46,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only compiler params; absent/renamed on some builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BM = 512
 DEFAULT_BN = 512
+# fp32 products on the MXU. At its default, Mosaic multiplies fp32
+# operands in one bf16 pass: on a v5e that moved ΔW at (2048, 2048, r=512)
+# by up to 9% of its largest value against the fp32 oracle.
+MXU_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _kernel(corr_ref, g_ref, p_ref, m_ref, v_ref,
@@ -71,6 +70,7 @@ def _kernel(corr_ref, g_ref, p_ref, m_ref, v_ref,
         g_ref[...].astype(jnp.float32),
         p_ref[...].astype(jnp.float32),
         preferred_element_type=jnp.float32,
+        precision=MXU_PRECISION,
     )
 
     @pl.when(k == n_steps - 1)
@@ -106,6 +106,7 @@ def _kernel_bp(corr_ref, g_ref, p_ref, m_ref, v_ref,
             g_ref[...].astype(jnp.float32),
             p_ref[...].astype(jnp.float32),
             preferred_element_type=jnp.float32,
+            precision=MXU_PRECISION,
         )
 
     @pl.when(k == kn - 1)
@@ -129,6 +130,7 @@ def _kernel_bp(corr_ref, g_ref, p_ref, m_ref, v_ref,
             acc_ref[...], p_ref[...].astype(jnp.float32),
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=MXU_PRECISION,
         )
 
 
@@ -155,17 +157,53 @@ def park_out_index(kn):
     return lambda i, k: (i, jnp.maximum(k - kn, 0))
 
 
+# Mosaic's default scoped-VMEM limit for one kernel on TPU v5e, less 2 MiB
+# for what the footprint estimates below do not see.
+SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+TILE_BUDGET_BYTES = SCOPED_VMEM_BYTES - 2 * 1024 * 1024
+_MIN_BN = 128
+_MIN_BM = 32
+
+
+def plan_two_phase_tiles(m, n, bm, bn, footprint, budget=TILE_BUDGET_BYTES):
+    """Row/column tiles for a two-phase kernel, shrunk until they fit VMEM.
+
+    ``bm``/``bn`` are first clamped to the problem. While
+    ``footprint(bm, bn)`` (bytes) exceeds ``budget``, ``bn`` halves down
+    to 128, then ``bm`` halves down to 32. Shrinking ``bn`` first keeps the
+    HBM traffic unchanged: P is re-streamed once per row block, whatever
+    the column block. Returns the last candidate even if it still does not
+    fit; the compiler then reports the excess."""
+    bm_eff = min(bm, max(8, m))
+    bn_eff = min(bn, max(128, n))
+    while footprint(bm_eff, bn_eff) > budget:
+        if bn_eff > _MIN_BN:
+            bn_eff //= 2
+        elif bm_eff > _MIN_BM:
+            bm_eff //= 2
+        else:
+            break
+    return bm_eff, bn_eff
+
+
+def bp_vmem_bytes(bm, bn, r, g_itemsize):
+    """Scoped VMEM of the fp32 back-projection kernel: double-buffered
+    G/P/M/V input and M'/V'/ΔW output tiles, the (bm, r) accumulator, and
+    the temporaries of the fp32 (``MXU_PRECISION``) products: two (bm, bn)
+    and two (bn, r) tiles and one (bm, r). Fitted to what the v5e compiler
+    asks for: at r=512 with fp32 G, 19.2 MiB at (bm, bn) = (512, 512),
+    16.3 at (512, 256), 12.5 at (512, 128); this gives 20, 15, 12.5."""
+    tile = bm * r * 4
+    inputs = bm * bn * g_itemsize + bn * r * 4 + 2 * tile
+    outputs = 2 * tile + bm * bn * 4
+    temps = 2 * bm * bn * 4 + 2 * bn * r * 4 + tile
+    return 2 * (inputs + outputs) + tile + temps
+
+
 def two_phase_compiler_params():
     """dimension_semantics for (parallel rows, arbitrary two-phase inner
-    dim), tolerant of the CompilerParams/TPUCompilerParams rename."""
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        )
-    except Exception:  # older naming
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        )
+    dim)."""
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
 @functools.partial(
@@ -226,19 +264,9 @@ def coap_fused_update_pallas(
         out_shape=out_shape,
         interpret=interpret,
     )
-    if _HAS_PLTPU:
-        kwargs["scratch_shapes"] = [pltpu.VMEM((bm_eff, r), jnp.float32)]
-        if not interpret:
-            try:
-                kwargs["compiler_params"] = pltpu.CompilerParams(
-                    dimension_semantics=("parallel", "arbitrary")
-                )
-            except Exception:  # older naming
-                kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-                    dimension_semantics=("parallel", "arbitrary")
-                )
-    else:  # pragma: no cover
-        raise RuntimeError("Pallas TPU backend unavailable; use ops ref path")
+    kwargs["scratch_shapes"] = [pltpu.VMEM((bm_eff, r), jnp.float32)]
+    if not interpret:
+        kwargs["compiler_params"] = two_phase_compiler_params()
 
     new_m, new_v, delta = pl.pallas_call(kernel, **kwargs)(
         corr, g_p, p_p, m_p, v_p
@@ -269,8 +297,10 @@ def coap_fused_update_bp_pallas(
     t = count.astype(jnp.float32)
     corr = jnp.stack([1.0 - b1**t, 1.0 - b2**t])
 
-    bm_eff = min(bm, max(8, m_dim))
-    bn_eff = min(bn, max(128, n_dim))
+    gi = jnp.dtype(g.dtype).itemsize
+    bm_eff, bn_eff = plan_two_phase_tiles(
+        m_dim, n_dim, bm, bn, lambda a, b: bp_vmem_bytes(a, b, r, gi)
+    )
     g_p = _pad_to(_pad_to(g, bm_eff, 0), bn_eff, 1)
     p_p = _pad_to(p, bn_eff, 0)
     m_p = _pad_to(m.astype(jnp.float32), bm_eff, 0)
@@ -304,12 +334,9 @@ def coap_fused_update_bp_pallas(
         out_shape=out_shape,
         interpret=interpret,
     )
-    if _HAS_PLTPU:
-        kwargs["scratch_shapes"] = [pltpu.VMEM((bm_eff, r), jnp.float32)]
-        if not interpret:
-            kwargs["compiler_params"] = two_phase_compiler_params()
-    else:  # pragma: no cover
-        raise RuntimeError("Pallas TPU backend unavailable; use ops ref path")
+    kwargs["scratch_shapes"] = [pltpu.VMEM((bm_eff, r), jnp.float32)]
+    if not interpret:
+        kwargs["compiler_params"] = two_phase_compiler_params()
 
     new_m, new_v, dw = pl.pallas_call(kernel, **kwargs)(
         corr, g_p, p_p, m_p, v_p

@@ -60,13 +60,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only compiler params; absent/renamed on some builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.coap_update import _pad_to as _pad_to_axis
 
@@ -75,6 +69,19 @@ _EPS = 1e-12  # must match core/correlation._EPS exactly (oracle parity)
 _MIN_BM = 8
 _DEFAULT_VMEM_BUDGET = 16 * 1024 * 1024  # bytes/core, TPU VMEM
 _VMEM_ENV = "REPRO_EQN6_VMEM_BUDGET"
+
+
+# The kernel's products run at the default MXU precision (one bf16 pass
+# for fp32 operands on TPU), pinned so that an ambient
+# ``jax.default_matmul_precision`` cannot change them: ``eqn6_vmem_bytes``
+# plans for these, and under HIGHEST the compiler wants more scoped VMEM
+# than the plan gives (ROADMAP A2).
+MXU_PRECISION = jax.lax.Precision.DEFAULT
+_dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                         precision=MXU_PRECISION)
+_dot_general = functools.partial(
+    jax.lax.dot_general, preferred_element_type=jnp.float32,
+    precision=MXU_PRECISION)
 
 
 class Eqn6VmemError(RuntimeError):
@@ -125,14 +132,7 @@ def plan_bm(m: int, n: int, r: int, bm: int = DEFAULT_BM,
 
 def _sequential_compiler_params():
     """Both grid dims carry state (SGD steps outer, row sweep inner)."""
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")
-        )
-    except Exception:  # older naming
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")
-        )
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary"))
 
 
 def _eqn6_kernel(p_ref, g_ref, mp_ref, p_out_ref, val_ref, grad_ref,
@@ -166,10 +166,9 @@ def _eqn6_kernel(p_ref, g_ref, mp_ref, p_out_ref, val_ref, grad_ref,
         @pl.when(k == 0)
         def _start_sweep():
             # PᵀP from the resident (possibly already-updated) P.
-            ptp_s[...] = jax.lax.dot_general(
+            ptp_s[...] = _dot_general(
                 p_s[...], p_s[...],
                 dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
             )
             a_s[...] = jnp.zeros_like(a_s)
             c_s[...] = jnp.zeros_like(c_s)
@@ -184,21 +183,19 @@ def _eqn6_kernel(p_ref, g_ref, mp_ref, p_out_ref, val_ref, grad_ref,
         if normalize:  # scale-invariant variant: tiles scaled by 1/rms
             g = g * sc_s[3]
             mp = mp * sc_s[3]
-        gp = jnp.dot(g, p_s[...], preferred_element_type=jnp.float32)  # (bm, r)
-        a_s[...] += jax.lax.dot_general(
+        gp = _dot(g, p_s[...])  # (bm, r)
+        a_s[...] += _dot_general(
             gp, gp, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
         )
-        c_s[...] += jax.lax.dot_general(
+        c_s[...] += _dot_general(
             g, gp, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
         )
         gn2 = jnp.sum(g * g, axis=1, keepdims=True)  # (bm, 1)
         sc_s[0] = sc_s[0] + jnp.sum(gn2)
         # ‖M̂ᵢ‖² and ⟨M̂ᵢ, Gᵢ⟩ via PᵀP / GP — M̂ never formed. Padded rows
         # (zero G and M) contribute exactly 0 everywhere: denom reduces to
         # eps and every numerator is 0.
-        w = jnp.dot(mp, ptp_s[...], preferred_element_type=jnp.float32)
+        w = _dot(mp, ptp_s[...])
         mh2 = jnp.sum(w * mp, axis=1, keepdims=True)
         inner = jnp.sum(mp * gp, axis=1, keepdims=True)
         mh = jnp.sqrt(mh2)
@@ -207,13 +204,11 @@ def _eqn6_kernel(p_ref, g_ref, mp_ref, p_out_ref, val_ref, grad_ref,
         sc_s[1] = sc_s[1] + jnp.sum(inner / denom)
         alpha = 1.0 / (m_true * denom)
         beta = inner / (m_true * (mh * mh2 * gn + eps))  # mh³ = mh·mh²
-        e_s[...] += jax.lax.dot_general(
+        e_s[...] += _dot_general(
             g, alpha * mp, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
         )
-        f_s[...] += jax.lax.dot_general(
+        f_s[...] += _dot_general(
             beta * mp, mp, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
         )
 
         @pl.when(k == nm - 1)
@@ -229,16 +224,14 @@ def _eqn6_kernel(p_ref, g_ref, mp_ref, p_out_ref, val_ref, grad_ref,
             mn = m_true * n_true
             v_mse = (jnp.sum(a * ptp) - 2.0 * tr_a + sc_s[0]) / mn
             g_mse = (2.0 / mn) * (
-                jnp.dot(p_cur, a, preferred_element_type=jnp.float32)
+                _dot(p_cur, a)
                 - 2.0 * c
-                + jnp.dot(c, ptp, preferred_element_type=jnp.float32)
+                + _dot(c, ptp)
             )
             v_cos = sc_s[1] / m_true
-            g_cos = e_s[...] - jnp.dot(
-                p_cur, f_s[...], preferred_element_type=jnp.float32
-            )
+            g_cos = e_s[...] - _dot(p_cur, f_s[...])
             grad = g_mse * (1.0 - v_cos) - g_cos * v_mse
-            val_ref[0] = v_mse * (1.0 - v_cos)
+            val_ref[0, 0] = v_mse * (1.0 - v_cos)
             grad_ref[...] = grad
             new_p = p_cur - lr * grad
             p_s[...] = new_p  # next SGD step (outer grid dim) sees the update
@@ -311,7 +304,7 @@ def eqn6_sgd_update_pallas(
     )
     out_shape = [
         jax.ShapeDtypeStruct((np_pad, r_pad), jnp.float32),  # new P
-        jax.ShapeDtypeStruct((1,), jnp.float32),  # last objective value
+        jax.ShapeDtypeStruct((1, 1), jnp.float32),  # last objective value
         jax.ShapeDtypeStruct((np_pad, r_pad), jnp.float32),  # last grad
     ]
     in_specs = [
@@ -321,7 +314,9 @@ def eqn6_sgd_update_pallas(
     ]
     out_specs = [
         pl.BlockSpec((np_pad, r_pad), lambda s, k: (0, 0)),
-        pl.BlockSpec((1,), lambda s, k: (0,)),
+        # Scalar objective in a (1, 1) SMEM block: Mosaic stores no scalar
+        # into VMEM, and two dims keep the block legal under vmap.
+        pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((np_pad, r_pad), lambda s, k: (0, 0)),
     ]
     kwargs = dict(
@@ -331,24 +326,21 @@ def eqn6_sgd_update_pallas(
         out_shape=out_shape,
         interpret=interpret,
     )
-    if _HAS_PLTPU:
-        kwargs["scratch_shapes"] = [
-            pltpu.VMEM((np_pad, r_pad), jnp.float32),  # resident P
-            pltpu.VMEM((r_pad, r_pad), jnp.float32),  # PᵀP
-            pltpu.VMEM((r_pad, r_pad), jnp.float32),  # A
-            pltpu.VMEM((np_pad, r_pad), jnp.float32),  # C
-            pltpu.VMEM((np_pad, r_pad), jnp.float32),  # E
-            pltpu.VMEM((r_pad, r_pad), jnp.float32),  # F
-            pltpu.SMEM((4,), jnp.float32),  # ‖G‖², Σ row-cos, ΣG²_raw, 1/rms
-        ]
-        if not interpret:
-            kwargs["compiler_params"] = _sequential_compiler_params()
-    else:  # pragma: no cover
-        raise RuntimeError("Pallas TPU backend unavailable; use ops ref path")
+    kwargs["scratch_shapes"] = [
+        pltpu.VMEM((np_pad, r_pad), jnp.float32),  # resident P
+        pltpu.VMEM((r_pad, r_pad), jnp.float32),  # PᵀP
+        pltpu.VMEM((r_pad, r_pad), jnp.float32),  # A
+        pltpu.VMEM((np_pad, r_pad), jnp.float32),  # C
+        pltpu.VMEM((np_pad, r_pad), jnp.float32),  # E
+        pltpu.VMEM((r_pad, r_pad), jnp.float32),  # F
+        pltpu.SMEM((4,), jnp.float32),  # ‖G‖², Σ row-cos, ΣG²_raw, 1/rms
+    ]
+    if not interpret:
+        kwargs["compiler_params"] = _sequential_compiler_params()
 
     p_new, val, grad = pl.pallas_call(kernel, **kwargs)(p_p, g_p, mp_p)
     return (
         p_new[:n_dim, :r].astype(p.dtype),
-        val[0],
+        val[0, 0],
         grad[:n_dim, :r],
     )

@@ -30,13 +30,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 DEFAULT_QB = 512
@@ -195,12 +189,9 @@ def _pad_seq(x, blk):
 
 def _pallas_kwargs(interpret, semantics):
     kw = dict(interpret=interpret)
-    if _HAS_PLTPU and not interpret:
-        try:
-            kw["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=semantics)
-        except Exception:  # pragma: no cover
-            pass
+    if not interpret:
+        kw["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=semantics)
     return kw
 
 
@@ -244,12 +235,11 @@ def _fwd(q, k, v, scale, causal, window, softcap, qb, kb, interpret):
         ],
         **_pallas_kwargs(interpret, ("parallel", "parallel", "arbitrary")),
     )
-    if _HAS_PLTPU:
-        kwargs["scratch_shapes"] = [
-            pltpu.VMEM((qb_e, 1), jnp.float32),
-            pltpu.VMEM((qb_e, 1), jnp.float32),
-            pltpu.VMEM((qb_e, hd), jnp.float32),
-        ]
+    kwargs["scratch_shapes"] = [
+        pltpu.VMEM((qb_e, 1), jnp.float32),
+        pltpu.VMEM((qb_e, 1), jnp.float32),
+        pltpu.VMEM((qb_e, hd), jnp.float32),
+    ]
     o, lse = pl.pallas_call(kernel, **kwargs)(qp, kp, vp)
     return o[:, :t], (q, k, v, o[:, :t], lse[:, :t])
 
@@ -302,11 +292,10 @@ def _bwd_rule(scale, causal, window, softcap, qb, kb, interpret, res, do):
         ],
         **_pallas_kwargs(interpret, ("parallel", "parallel", "arbitrary")),
     )
-    if _HAS_PLTPU:
-        kwargs["scratch_shapes"] = [
-            pltpu.VMEM((kb_e, hd), jnp.float32),
-            pltpu.VMEM((kb_e, hd), jnp.float32),
-        ]
+    kwargs["scratch_shapes"] = [
+        pltpu.VMEM((kb_e, hd), jnp.float32),
+        pltpu.VMEM((kb_e, hd), jnp.float32),
+    ]
     dk_per_qh, dv_per_qh = pl.pallas_call(dkv_kernel, **kwargs)(
         qp, kp, vp, dop, lse_p, delta_p
     )
@@ -332,8 +321,7 @@ def _bwd_rule(scale, causal, window, softcap, qb, kb, interpret, res, do):
         out_shape=jax.ShapeDtypeStruct((bh, tp, hd), q.dtype),
         **_pallas_kwargs(interpret, ("parallel", "parallel", "arbitrary")),
     )
-    if _HAS_PLTPU:
-        kwargs["scratch_shapes"] = [pltpu.VMEM((qb_e, hd), jnp.float32)]
+    kwargs["scratch_shapes"] = [pltpu.VMEM((qb_e, hd), jnp.float32)]
     dq = pl.pallas_call(dq_kernel, **kwargs)(
         qp, kp, vp, dop, lse_p, delta_p
     )[:, :t]

@@ -1,9 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels with backend dispatch.
 
-On TPU the Pallas implementations run natively; elsewhere (this CPU
-container) we execute the ``ref.py`` oracle, or the Pallas body under
-``interpret=True`` when ``REPRO_PALLAS=interpret`` is set (used by the kernel
-test suite). The numerics are identical by construction (tests enforce it).
+On TPU the Pallas implementations run natively, except in a program sharded
+over an ambient mesh (see ``_mode``); elsewhere (CPU) we execute the
+``ref.py`` oracle, or the Pallas body under ``interpret=True`` when
+``REPRO_PALLAS=interpret`` is set (used by the kernel test suite). The
+numerics are identical by construction (tests enforce it).
 """
 from __future__ import annotations
 
@@ -12,17 +13,35 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.kernels import ref
 
 _MODE_ENV = "REPRO_PALLAS"
 
 
-def _mode() -> str:
+def _mode(kernel: str = "") -> str:
+    """'pallas' | 'interpret' | 'ref' for a trace of ``kernel``."""
     forced = os.environ.get(_MODE_ENV, "")
     if forced:
-        return forced  # 'pallas' | 'interpret' | 'ref'
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+        return forced
+    if jax.default_backend() != "tpu":
+        return "ref"
+    # XLA cannot partition a Mosaic kernel. In a program sharded over the
+    # ambient mesh (``jax.set_mesh``) the jnp path runs, which XLA does
+    # partition; inside a shard_map manual over every sharded axis the body
+    # is per device and the kernels run. jit keys its cache on the ambient
+    # mesh, so the two never share a trace. Each trace that takes the jnp
+    # path this way counts under ``kernels/on_mesh/<kernel>``.
+    mesh = jax.sharding.get_abstract_mesh()
+    if any(size > 1 and kind != AxisType.Manual
+           for size, kind in zip(mesh.axis_sizes, mesh.axis_types)):
+        if kernel:
+            from repro.obs.registry import get_registry
+
+            get_registry().inc(f"kernels/on_mesh/{kernel}")
+        return "ref"
+    return "pallas"
 
 
 def _interpret_flag():
@@ -33,7 +52,7 @@ def _interpret_flag():
 def coap_fused_update(g, p, m, v, count, b1=0.9, b2=0.999, eps=1e-8):
     """Fused G@P + Adam moment EMA + bias-corrected ΔW_proj. See kernel
     ``coap_update.py`` for the TPU implementation and tiling rationale."""
-    if _mode() == "ref":
+    if _mode("coap_fused_update") == "ref":
         return ref.coap_fused_update(g, p, m, v, count, b1=b1, b2=b2, eps=eps)
     from repro.kernels import coap_update
 
@@ -47,7 +66,7 @@ def coap_fused_update_bp(g, p, m, v, count, b1=0.9, b2=0.999, eps=1e-8):
     """Back-projection-fused step: returns (m', v', ΔW) with ΔW = Δ_proj Pᵀ
     produced as a second MXU stage of the same kernel — Δ_proj never hits
     HBM. See ``coap_update.coap_fused_update_bp_pallas``."""
-    if _mode() == "ref":
+    if _mode("coap_fused_update_bp") == "ref":
         return ref.coap_fused_update_bp(g, p, m, v, count, b1=b1, b2=b2, eps=eps)
     from repro.kernels import coap_update
 
@@ -63,7 +82,7 @@ def coap_fused_update_q8(
 ):
     """Single-pass 8-bit COAP step (project + dequant + Adam + requant +
     back-project in one kernel; row-block codec). See ``quant8``."""
-    if _mode() == "ref":
+    if _mode("coap_fused_update_q8") == "ref":
         return ref.coap_fused_update_q8(
             g, p, m_q, m_scale, v_q, v_scale, count,
             b1=b1, b2=b2, eps=eps, block=block,
@@ -101,7 +120,7 @@ def rowblock_code_stats(q, scale, block=ref.QUANT_BLOCK):
 
 @functools.partial(jax.jit, static_argnames=("block",))
 def quantize_blockwise(x, block=ref.QUANT_BLOCK):
-    if _mode() == "ref":
+    if _mode("quantize_blockwise") == "ref":
         return ref.quantize_blockwise(x, block)
     from repro.kernels import quant8
 
@@ -110,7 +129,7 @@ def quantize_blockwise(x, block=ref.QUANT_BLOCK):
 
 @functools.partial(jax.jit, static_argnames=("shape", "dtype", "block"))
 def dequantize_blockwise(q, scale, shape, dtype=jnp.float32, block=ref.QUANT_BLOCK):
-    if _mode() == "ref":
+    if _mode("dequantize_blockwise") == "ref":
         return ref.dequantize_blockwise(q, scale, shape, dtype)
     from repro.kernels import quant8
 
@@ -124,7 +143,7 @@ def quantized_adam_update(
     g_proj, m_q, m_scale, v_q, v_scale, count, b1=0.9, b2=0.999, eps=1e-8,
     block=ref.QUANT_BLOCK,
 ):
-    if _mode() == "ref":
+    if _mode("quantized_adam_update") == "ref":
         return ref.quantized_adam_update(
             g_proj, m_q, m_scale, v_q, v_scale, count, b1, b2, eps, block
         )
@@ -197,7 +216,7 @@ def eqn6_sgd_update(p, g, m_proj, lr=0.1, steps=1, normalize=False):
     at any row-tile size (wide layers; ``eqn6.plan_bm``), the dispatch
     falls back to the unfused jnp oracle — identical numerics, no
     uncompilable kernel."""
-    if _mode() == "ref":
+    if _mode("eqn6_sgd_update") == "ref":
         return _eqn6_ref(p, g, m_proj, lr, steps, normalize)
     from repro.kernels import eqn6
 
@@ -217,7 +236,7 @@ def eqn6_sgd_update(p, g, m_proj, lr=0.1, steps=1, normalize=False):
 
 
 def rmsnorm(x, scale, eps=1e-6):
-    if _mode() == "ref":
+    if _mode("rmsnorm") == "ref":
         return ref.rmsnorm(x, scale, eps)
     from repro.kernels import rmsnorm as _rk
 

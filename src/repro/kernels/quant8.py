@@ -47,20 +47,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref as _ref
 from repro.kernels.ref import QUANT_BLOCK, QUANT_DELTA_CLIP, rowblock_nblocks
 
 ROWS_PER_PROGRAM = 64  # (64, 256) int8 tiles: fits the int8 (32,128) layout
 DEFAULT_BM = 512  # fused-q8 row tile: fewer P sweeps (2·ceil(m/bm)·nr words
-# of internal re-stream); working set ~7MB at r=1024 stays under 16MB VMEM.
+# of internal re-stream); ``plan_two_phase_tiles`` shrinks the tiles to fit
+# VMEM (``q8_vmem_bytes``).
 DEFAULT_BN = 512  # fused-q8 G column block
 
 
@@ -118,9 +113,11 @@ def _row_pad(x, rows):
 # shared two-phase grid pieces (same tiling semantics as the fp32 fused
 # kernel — see coap_update.py)
 from repro.kernels.coap_update import (  # noqa: E402
+    MXU_PRECISION,
     _pad_to as _pad_to_axis,
     park_out_index,
     pin_g_index,
+    plan_two_phase_tiles,
     two_phase_compiler_params,
 )
 
@@ -240,6 +237,20 @@ def _requant_rowblock_tile(x, q_ref, s_ref, block):
     s_ref[...] = s
 
 
+def q8_vmem_bytes(bm, bn, r, nblk, g_itemsize):
+    """Scoped VMEM of the fused int8 kernel: double-buffered G/P and int8
+    M/V (+ lane-padded scale) tiles in and out, the (bm, r) accumulator,
+    and six (bm, r) fp32 temporaries of the dequant/Adam/requant epilogue.
+    At r=512 with fp32 G the v5e compiler asks 16.1 MiB at (bm, bn) =
+    (512, 512), 13.1 at (512, 256), 11.7 at (512, 128); this gives 17,
+    14, 12.5."""
+    tile = bm * r * 4
+    state = bm * r + bm * (-(-nblk // 128) * 128) * 4  # int8 codes + scales
+    inputs = bm * bn * g_itemsize + bn * r * 4 + 2 * state
+    outputs = 2 * state + bm * bn * 4
+    return 2 * (inputs + outputs) + tile + 6 * tile
+
+
 def _fused8_proj_kernel(corr_ref, g_ref, p_ref, mq_ref, ms_ref, vq_ref, vs_ref,
                         nmq_ref, nms_ref, nvq_ref, nvs_ref, dw_ref, acc_ref,
                         *, b1, b2, eps, kn, block):
@@ -255,6 +266,7 @@ def _fused8_proj_kernel(corr_ref, g_ref, p_ref, mq_ref, ms_ref, vq_ref, vs_ref,
             g_ref[...].astype(jnp.float32),
             p_ref[...].astype(jnp.float32),
             preferred_element_type=jnp.float32,
+            precision=MXU_PRECISION,
         )
 
     @pl.when(k == kn - 1)
@@ -276,6 +288,7 @@ def _fused8_proj_kernel(corr_ref, g_ref, p_ref, mq_ref, ms_ref, vq_ref, vs_ref,
             acc_ref[...], p_ref[...].astype(jnp.float32),
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=MXU_PRECISION,
         )
 
 
@@ -307,8 +320,10 @@ def coap_fused_update_q8_pallas(
     t = count.astype(jnp.float32)
     corr = jnp.stack([1.0 - b1**t, 1.0 - b2**t])
 
-    bm_eff = min(bm, max(8, m_dim))
-    bn_eff = min(bn, max(128, n_dim))
+    gi = jnp.dtype(g.dtype).itemsize
+    bm_eff, bn_eff = plan_two_phase_tiles(
+        m_dim, n_dim, bm, bn, lambda a, b: q8_vmem_bytes(a, b, r, nblk, gi)
+    )
     g_p = _pad_to_axis(_pad_to_axis(g, bm_eff, 0), bn_eff, 1)
     p_p = _pad_to_axis(p, bn_eff, 0)
     mq_p = _pad_to_axis(m_q, bm_eff, 0)
@@ -347,12 +362,9 @@ def coap_fused_update_q8_pallas(
         ],
         interpret=interpret,
     )
-    if _HAS_PLTPU:
-        kwargs["scratch_shapes"] = [pltpu.VMEM((bm_eff, r), jnp.float32)]
-        if not interpret:
-            kwargs["compiler_params"] = two_phase_compiler_params()
-    else:  # pragma: no cover
-        raise RuntimeError("Pallas TPU backend unavailable; use ops ref path")
+    kwargs["scratch_shapes"] = [pltpu.VMEM((bm_eff, r), jnp.float32)]
+    if not interpret:
+        kwargs["compiler_params"] = two_phase_compiler_params()
 
     nmq, nms, nvq, nvs, dw = pl.pallas_call(kernel, **kwargs)(
         corr, g_p, p_p, mq_p, ms_p, vq_p, vs_p

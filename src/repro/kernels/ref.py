@@ -19,6 +19,9 @@ QUANT_BLOCK = 256  # VPU lane width (128) x 2; absmax granularity for int8 state
 # construction; our TPU-friendly linear codec instead clips the bias-corrected
 # update elementwise (normal Adam updates are |d| <~ 3, so 5 is inert).
 QUANT_DELTA_CLIP = 5.0
+# The optimizer's projections are fp32 products, as in the fused kernels
+# (``coap_update.MXU_PRECISION``); XLA's default on TPU is one bf16 pass.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
@@ -39,7 +42,8 @@ def coap_fused_update(
     Broadcasts over leading (layer/expert) stack axes.
     """
     g_proj = jnp.einsum(
-        "...mn,...nr->...mr", g.astype(jnp.float32), p.astype(jnp.float32)
+        "...mn,...nr->...mr", g.astype(jnp.float32), p.astype(jnp.float32),
+        precision=HIGHEST,
     )
     new_m = b1 * m + (1.0 - b1) * g_proj
     new_v = b2 * v + (1.0 - b2) * jnp.square(g_proj)
@@ -65,7 +69,8 @@ def coap_fused_update_bp(
     update — Δ_proj is never a caller-visible (HBM) tensor.
     """
     new_m, new_v, delta = coap_fused_update(g, p, m, v, count, b1, b2, eps)
-    dw = jnp.einsum("...mr,...nr->...mn", delta, p.astype(jnp.float32))
+    dw = jnp.einsum("...mr,...nr->...mn", delta, p.astype(jnp.float32),
+                    precision=HIGHEST)
     return new_m, new_v, dw
 
 
@@ -253,14 +258,16 @@ def coap_fused_update_q8(
     m = dequantize_rowblock(m_q, m_scale, block)
     v = dequantize_rowblock(v_q, v_scale, block)
     g_proj = jnp.einsum(
-        "...mn,...nr->...mr", g.astype(jnp.float32), p.astype(jnp.float32)
+        "...mn,...nr->...mr", g.astype(jnp.float32), p.astype(jnp.float32),
+        precision=HIGHEST,
     )
     new_m = b1 * m + (1.0 - b1) * g_proj
     new_v = b2 * v + (1.0 - b2) * jnp.square(g_proj)
     t = count.astype(jnp.float32)
     delta = (new_m / (1.0 - b1**t)) / (jnp.sqrt(new_v / (1.0 - b2**t)) + eps)
     delta = jnp.clip(delta, -QUANT_DELTA_CLIP, QUANT_DELTA_CLIP)
-    dw = jnp.einsum("...mr,...nr->...mn", delta, p.astype(jnp.float32))
+    dw = jnp.einsum("...mr,...nr->...mn", delta, p.astype(jnp.float32),
+                    precision=HIGHEST)
     nmq, nms = quantize_rowblock(new_m, block)
     nvq, nvs = quantize_rowblock(new_v, block)
     return nmq, nms, nvq, nvs, dw

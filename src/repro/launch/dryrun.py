@@ -224,7 +224,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         from repro.kernels import ops as kops
 
         kops.reset_eqn6_fallbacks()
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(step, in_shardings=in_shardings)
             lowered = jitted.lower(*args)
             t_lower = time.time() - t0

@@ -39,9 +39,13 @@ def make_production_mesh(*, multi_pod: bool = False, pods: int = 2):
 
 
 def make_test_mesh(shape=(2, 2, 2), axes=("pod", "data", "model")):
-    """Small mesh for the 8-device subprocess tests."""
+    """Small Auto-axis mesh over the first ``prod(shape)`` devices, for the
+    multi-device tests and the chip smoke run."""
+    n = 1
+    for s in shape:
+        n *= s
     return jax.make_mesh(
-        shape, axes,
+        shape, axes, devices=jax.devices()[:n],
         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
     )
 

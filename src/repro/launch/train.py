@@ -30,6 +30,7 @@ import os
 from repro.configs import get_config, get_smoke
 from repro.core.api import OptimizerConfig, make_optimizer
 from repro.data.synthetic import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.optim import warmup_cosine_schedule
 from repro.train.fault_tolerance import run_with_restart
@@ -190,6 +191,7 @@ def main():
     ap.add_argument("--backoff-cap", type=float, default=30.0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
     data = SyntheticLM(vocab=cfg.vocab_size, order=2, noise=0.1)

@@ -46,6 +46,7 @@ def main(argv=None) -> int:
     from repro.configs import get_config, get_smoke
     from repro.core.api import OptimizerConfig
     from repro.data.synthetic import SyntheticLM
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.model import build_model
     from repro.train.elastic import (
         EXIT_DRAINED,
@@ -57,6 +58,7 @@ def main(argv=None) -> int:
     from repro.obs.trace import get_tracer
     from repro.train.fault_tolerance import DrainPreemption, Heartbeat
 
+    enable_compile_cache()
     ecfg = elastic_config_from_dict(spec["elastic"])
     if ecfg.trace_path:
         trace_configure(ecfg.trace_path, host=ecfg.host_id)

@@ -24,7 +24,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models.layers import ParamDef, swiglu
 
 
@@ -177,7 +176,7 @@ def moe_apply_local_ep(params, x, *, n_experts: int, top_k: int,
         return out, aux
 
     bspec = manual if len(manual) > 1 else manual[0]
-    return compat.shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), P(bspec, None, None)),
         out_specs=(P(bspec, None, None), P()),

@@ -26,6 +26,7 @@ from repro.core.coap_adam import (
 )
 from repro.core.projector import ProjectionRules
 from repro.kernels import ops as kops
+from repro.launch.mesh import make_test_mesh
 
 
 def _congruent_params(n_leaves=8, shape=(96, 64), odd=True):
@@ -180,7 +181,6 @@ def test_compressed_update_accepts_quantized_states():
     tests/test_distributed.py."""
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.distributed.compression import compressed_update
 
     cfg = _cfg(quantize=True, use_fused_kernel=False, t_update=2, lam=2)
@@ -188,12 +188,12 @@ def test_compressed_update_accepts_quantized_states():
     tx = scale_by_projected_adam(cfg)
     state = tx.init(params)
     g = _grads(params)
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_test_mesh((1,), ("pod",))
 
     def body(gg, st):
         return compressed_update(cfg, gg, st, "pod")
 
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
         check_vma=False, axis_names={"pod"},
     )

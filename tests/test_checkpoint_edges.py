@@ -335,6 +335,7 @@ def test_elastic_reshard_v1_checkpoint_to_v2_template():
     test_distributed.run_sub("""
         import json, os, tempfile
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_test_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core import stacked_state as ss
         from repro.core.coap_adam import (
@@ -370,7 +371,7 @@ def test_elastic_reshard_v1_checkpoint_to_v2_template():
             leaves=ss.encode(
                 layout_v1, treedef.flatten_up_to(st_p.leaves)),
         )
-        mesh4 = jax.make_mesh((4,), ("data",), devices=jax.devices()[:4])
+        mesh4 = make_test_mesh((4,), ("data",))
         st_sharded = jax.tree_util.tree_map(
             lambda x: jax.device_put(x, NamedSharding(mesh4, P())), st_v1)
         tmp = tempfile.mkdtemp()
@@ -383,7 +384,7 @@ def test_elastic_reshard_v1_checkpoint_to_v2_template():
         with open(os.path.join(cdir, "manifest.json"), "w") as f:
             json.dump(manifest, f)
 
-        mesh8 = jax.make_mesh((8,), ("data",))
+        mesh8 = make_test_mesh((8,), ("data",))
         template = jax.eval_shape(lambda: tx_s.init(params))
         specs = jax.tree_util.tree_map(
             lambda _: P(), template, is_leaf=lambda x: hasattr(x, "shape"))

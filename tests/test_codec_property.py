@@ -1,8 +1,8 @@
 """Property-based stacked-state codec tests (stacked-bucket/v2).
 
 Randomized pytrees mixing dense, projected and conv (Tucker-2) leaves —
-drawn through ``hypothesis`` (or the deterministic ``tests/conftest.py``
-shim when the real package is absent) — must satisfy, for every draw:
+drawn through ``hypothesis`` (derandomized by ``tests/conftest.py``) —
+must satisfy, for every draw:
 
   * ``decode(encode(x)) == x`` bit-for-bit, int8 codes and scales
     included, with ``leaf_view`` agreeing at every flat index;

@@ -28,9 +28,10 @@ def test_sharding_rules_unit():
     """Pure-python rule behaviour (no mesh devices needed beyond 8)."""
     run_sub("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_test_mesh
         from jax.sharding import PartitionSpec as P
         from repro.distributed import sharding as shd
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
         # standard 2D weight: embed->data, ffn->model
         s = shd.spec_for_axes(("embed", "ffn"), (128, 256), mesh)
         assert s == P("data", "model"), s
@@ -45,11 +46,75 @@ def test_sharding_rules_unit():
     """)
 
 
+def test_explicit_mesh_is_rejected_with_a_clear_error():
+    run_sub("""
+        import jax
+        from jax.sharding import AxisType
+        from repro.distributed import sharding as shd
+        from repro.launch.mesh import make_test_mesh
+        assert shd.current_mesh() is None
+        auto = make_test_mesh((2, 2), ("data", "model"))
+        with jax.set_mesh(auto):
+            assert shd.current_mesh().axis_names == ("data", "model")
+        explicit = jax.make_mesh((2, 2), ("data", "model"),
+                                 axis_types=(AxisType.Explicit,) * 2)
+        with jax.set_mesh(explicit):
+            try:
+                shd.current_mesh()
+            except ValueError as e:
+                assert "Explicit" in str(e), e
+            else:
+                raise AssertionError("explicit mesh accepted")
+        print("ok")
+    """, devices=4)
+
+
+def test_kernel_dispatch_follows_the_ambient_mesh():
+    """On TPU the kernels run unless the program is sharded over a
+    non-manual mesh axis (XLA cannot partition a Pallas kernel)."""
+    run_sub("""
+        import jax, jax.numpy as jnp
+        from jax.sharding import PartitionSpec as P
+        from repro.kernels import ops
+        from repro.launch.mesh import make_test_mesh
+        from repro.obs.registry import get_registry
+        jax.default_backend = lambda: "tpu"  # dispatch as on a chip
+        assert ops._mode() == "pallas"
+        with jax.set_mesh(make_test_mesh((2, 2), ("data", "model"))):
+            assert ops._mode() == "ref"
+            # a kernel's trace that a mesh sends to the jnp path is counted
+            assert ops._mode("rmsnorm") == "ref"
+        assert get_registry().snapshot()["counters"] == {
+            "kernels/on_mesh/rmsnorm": 1}
+        with jax.set_mesh(make_test_mesh((1,), ("pod",))):
+            assert ops._mode() == "pallas"
+        modes = []
+
+        def body(x):
+            modes.append(ops._mode())
+            return x
+
+        pod = make_test_mesh((2,), ("pod",))
+        with jax.set_mesh(pod):
+            jax.shard_map(body, mesh=pod, in_specs=P("pod"),
+                          out_specs=P("pod"))(jnp.ones(4))
+        pod_data = make_test_mesh((2, 2), ("pod", "data"))
+        with jax.set_mesh(pod_data):
+            jax.shard_map(body, mesh=pod_data, in_specs=P("pod"),
+                          out_specs=P("pod"), axis_names={"pod"})(
+                              jnp.ones(4))
+        assert modes == ["pallas", "ref"], modes
+        assert get_registry().get("kernels/on_mesh/rmsnorm") == 1
+        print("ok")
+    """, devices=4)
+
+
 def test_sharded_train_step_runs_and_matches_single_device():
     """A COAP train step under pjit on a (2,2,2) mesh must equal the
     unsharded step (same params/batch)."""
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_test_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_smoke
         from repro.models.model import build_model
@@ -72,9 +137,9 @@ def test_sharded_train_step_runs_and_matches_single_device():
         # single-device reference
         ref_state, ref_metrics = jax.jit(step)(state, batch)
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
         pspecs = model.param_specs(mesh)
-        with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+        with jax.set_mesh(mesh):
             bspec = shd.batch_specs(batch, mesh)
             bshard = jax.tree_util.tree_map(
                 lambda s: NamedSharding(mesh, s), bspec)
@@ -97,6 +162,7 @@ def test_crosspod_compression_matches_uncompressed():
     run_sub("""
         import dataclasses
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_test_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_smoke
         from repro.models.model import build_model
@@ -131,11 +197,11 @@ def test_crosspod_compression_matches_uncompressed():
             params, jax.tree_util.tree_map(lambda u: -lr * u, upd))
 
         # Compressed: 2 pods, per-pod half batches, r-rank cross-pod sync.
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
         state = TrainState(step=jnp.zeros([], jnp.int32), params=params,
                            opt_state=opt_state)
         step_fn = make_compressed_train_step(model, pcfg, mesh, lr)
-        with mesh:
+        with jax.set_mesh(mesh):
             bshard = jax.tree_util.tree_map(
                 lambda x: jax.device_put(
                     x, NamedSharding(mesh, P("pod"))), batch)
@@ -155,7 +221,7 @@ def test_crosspod_compression_matches_uncompressed():
         sstate = TrainState(step=jnp.zeros([], jnp.int32), params=params,
                             opt_state=stx.init(params))
         sstep_fn = make_compressed_train_step(model, scfg, mesh, lr)
-        with mesh:
+        with jax.set_mesh(mesh):
             snew_state, _ = jax.jit(sstep_fn)(sstate, bshard)
         for a, b in zip(jax.tree_util.tree_leaves(ref_params),
                         jax.tree_util.tree_leaves(snew_state.params)):
@@ -174,8 +240,8 @@ def test_crosspod_conv_compression_matches_uncompressed():
     Multi-step, so eqn6 refresh AND recal steps both cross pods."""
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_test_mesh
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.core.coap_adam import (
             ProjectedAdamConfig, scale_by_projected_adam)
         from repro.core.projector import ProjectionRules
@@ -207,8 +273,7 @@ def test_crosspod_conv_compression_matches_uncompressed():
             ref_upd, ref_state = step(g_mean, ref_state)
 
         # Compressed: per-pod gradients, core-only reduction each step.
-        mesh = jax.make_mesh((2,), ("pod",),
-                             devices=jax.devices()[:2])
+        mesh = make_test_mesh((2,), ("pod",))
         gstack = jax.tree_util.tree_map(
             lambda a, b: jnp.stack([a, b]), g0, g1)
         state = tx.init(params)
@@ -217,7 +282,7 @@ def test_crosspod_conv_compression_matches_uncompressed():
             mine = jax.tree_util.tree_map(lambda x: x[0], gg)
             return compressed_update(cfg, mine, st, "pod")
 
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             per_pod, mesh=mesh, in_specs=(P("pod"), P()),
             out_specs=(P(), P()), check_vma=False, axis_names={"pod"})
         for _ in range(4):
@@ -245,18 +310,18 @@ def test_elastic_checkpoint_reshard():
     run_sub("""
         import os, tempfile
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_test_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.train import checkpoint as ckpt
 
         tmp = tempfile.mkdtemp()
-        mesh4 = jax.make_mesh((2, 2), ("data", "model"),
-                              devices=jax.devices()[:4])
+        mesh4 = make_test_mesh((2, 2), ("data", "model"))
         w = jnp.arange(64 * 32, dtype=jnp.float32).reshape(64, 32)
         sharded = jax.device_put(w, NamedSharding(mesh4, P("data", "model")))
         state = {"w": sharded, "step": jnp.asarray(7)}
         ckpt.save(tmp, 7, state)
 
-        mesh8 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh8 = make_test_mesh((4, 2), ("data", "model"))
         template = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)
         specs = {"w": P("data", "model"), "step": P()}
@@ -274,6 +339,7 @@ def test_elastic_checkpoint_reshard_stacked_cross_mode():
     run_sub("""
         import tempfile
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_test_mesh
         from repro.core import stacked_state as ss
         from repro.core.coap_adam import (
             ProjectedAdamConfig, scale_by_projected_adam)
@@ -299,14 +365,14 @@ def test_elastic_checkpoint_reshard_stacked_cross_mode():
         tx_s, st_s = build(True)
         tx_p, st_p = build(False)
 
-        mesh4 = jax.make_mesh((4,), ("data",), devices=jax.devices()[:4])
+        mesh4 = make_test_mesh((4,), ("data",))
         from jax.sharding import NamedSharding, PartitionSpec as P
         st_sharded = jax.tree_util.tree_map(
             lambda x: jax.device_put(x, NamedSharding(mesh4, P())), st_s)
         tmp = tempfile.mkdtemp()
         ckpt.save(tmp, 1, st_sharded)
 
-        mesh8 = jax.make_mesh((8,), ("data",))
+        mesh8 = make_test_mesh((8,), ("data",))
         for tx_dst, want_state, label in [
                 (tx_p, st_p, "per-leaf"), (tx_s, st_s, "stacked")]:
             template = jax.eval_shape(lambda: tx_dst.init(params))
@@ -340,8 +406,8 @@ def test_crosspod_quantized_matches_single_pod():
     run_sub("""
         import dataclasses
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_test_mesh
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.core import stacked_state as ss
         from repro.core.coap_adam import (
             ProjectedAdamConfig, scale_by_projected_adam)
@@ -364,13 +430,13 @@ def test_crosspod_quantized_matches_single_pod():
                                         x.shape)
                 for i, x in enumerate(flat)])
 
-        mesh = jax.make_mesh((2,), ("pod",), devices=jax.devices()[:2])
+        mesh = make_test_mesh((2,), ("pod",))
         def run_compressed(ccfg, gstack_of, steps=4):
             state = scale_by_projected_adam(ccfg).init(params)
             def per_pod(gg, st):
                 mine = jax.tree_util.tree_map(lambda x: x[0], gg)
                 return compressed_update(ccfg, mine, st, "pod")
-            mapped = compat.shard_map(
+            mapped = jax.shard_map(
                 per_pod, mesh=mesh, in_specs=(P("pod"), P()),
                 out_specs=(P(), P()), check_vma=False, axis_names={"pod"})
             upd = None
@@ -461,8 +527,8 @@ def test_crosspod_sync_codes_int8_collective():
     run_sub("""
         import dataclasses
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_test_mesh
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.core.coap_adam import (
             ProjectedAdamConfig, scale_by_projected_adam)
         from repro.core.projector import ProjectionRules
@@ -470,7 +536,7 @@ def test_crosspod_sync_codes_int8_collective():
             _allreduce_codes, compressed_update)
         from repro.optim import apply_updates
 
-        mesh = jax.make_mesh((2,), ("pod",), devices=jax.devices()[:2])
+        mesh = make_test_mesh((2,), ("pod",))
         T, BLOCK = 12, 32
         xs = jax.random.normal(jax.random.key(0), (2, 4, 96))
 
@@ -485,7 +551,7 @@ def test_crosspod_sync_codes_int8_collective():
                 efs.append(ef)
             return acc, efs[-2], efs[-1], red
 
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             collective, mesh=mesh, in_specs=(P("pod"),),
             out_specs=(P(), P(), P(), P()), check_vma=False,
             axis_names={"pod"})
@@ -534,7 +600,7 @@ def test_crosspod_sync_codes_int8_collective():
             def per_pod(gg, st):
                 mine = jax.tree_util.tree_map(lambda x: x[0], gg)
                 return compressed_update(ccfg, mine, st, "pod")
-            mapped = compat.shard_map(
+            mapped = jax.shard_map(
                 per_pod, mesh=mesh, in_specs=(P("pod"), P()),
                 out_specs=(P(), P()), check_vma=False, axis_names={"pod"})
             for _ in range(steps):
@@ -576,11 +642,11 @@ def _compressed_runner(cfg, params):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.distributed.compression import compressed_update
+    from repro.launch.mesh import make_test_mesh
 
-    mesh = jax.make_mesh((1,), ("pod",))
-    return compat.shard_map(
+    mesh = make_test_mesh((1,), ("pod",))
+    return jax.shard_map(
         lambda gg, st: compressed_update(cfg, gg, st, "pod"),
         mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
         check_vma=False, axis_names={"pod"},

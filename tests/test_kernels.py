@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core import correlation
 from repro.kernels import ref
@@ -181,6 +181,7 @@ def test_quantized_adam_update_matches_ref(m, r, seed):
     scale_pow=st.integers(-6, 3),
     seed=st.integers(0, 100),
 )
+@example(m=9, r=256, scale_pow=3, seed=24)
 def test_rowblock_roundtrip(m, r, scale_pow, seed):
     """Codec invariants incl. ragged r (tail block shorter than 256)."""
     x = (10.0**scale_pow) * _rand((m, r), seed)
@@ -188,10 +189,12 @@ def test_rowblock_roundtrip(m, r, scale_pow, seed):
     assert q.shape == (m, r) and q.dtype == jnp.int8
     assert s.shape == (m, ref.rowblock_nblocks(r))
     back = ref.dequantize_rowblock(q, s)
-    # absmax codec: error <= scale/2 per element, scales per row-block
-    err = np.abs(np.asarray(x) - np.asarray(back))
+    # absmax codec: error <= scale/2 per element, scales per row-block,
+    # plus the fp32 rounding of the decoded value q·s (one ulp of x).
+    x_np = np.asarray(x)
+    err = np.abs(x_np - np.asarray(back))
     bound = np.repeat(np.asarray(s), ref.QUANT_BLOCK, axis=-1)[:, :r]
-    assert (err <= 0.5 * bound + 1e-12).all()
+    assert (err <= 0.5 * bound + np.spacing(np.abs(x_np))).all()
 
 
 def test_rowblock_matches_flat_codec_when_aligned():
@@ -213,9 +216,14 @@ def test_rowblock_matches_flat_codec_when_aligned():
     r=st.sampled_from([32, 48, 300]),
     count=st.integers(1, 500),
 )
+@example(m=168, n=520, r=300, count=1)
 def test_coap_fused_update_q8_exact_codes(m, n, r, count):
     """With a single n-block the kernel's G@P is the oracle's dot — the
-    requantized int8 states must be BIT-EXACT, scales/ΔW to fp32 ulp."""
+    requantized int8 states must be BIT-EXACT, scales/ΔW to fp32 ulp.
+
+    The oracle runs jitted, as ``kernels/ops`` runs it: XLA fuses its
+    elementwise epilogue the way it fuses the kernel body, while an eager
+    oracle rounds after every op and can move a code by one."""
     g = 0.1 * _rand((m, n), 0)
     p = _rand((n, r), 1) / np.sqrt(r)
     m0 = 0.05 * _rand((m, r), 2)
@@ -226,7 +234,7 @@ def test_coap_fused_update_q8_exact_codes(m, n, r, count):
     got = coap_fused_update_q8_pallas(
         g, p, mq, ms, vq, vs, cnt, interpret=True, bm=64, bn=1024
     )
-    want = ref.coap_fused_update_q8(g, p, mq, ms, vq, vs, cnt)
+    want = jax.jit(ref.coap_fused_update_q8)(g, p, mq, ms, vq, vs, cnt)
     for a, b, name in zip(got[:4:2], want[:4:2], ["mq", "vq"]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                       err_msg=name)

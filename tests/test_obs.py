@@ -231,9 +231,9 @@ def test_merge_snapshots_gauges_deterministic_by_ts():
 
 
 def test_merge_snapshots_counter_properties():
-    """Counter merging is associative and commutative (property test over
-    the deterministic hypothesis shim): any merge tree over any
-    permutation yields the same counter totals."""
+    """Counter merging is associative and commutative (a derandomized
+    hypothesis property): any merge tree over any permutation yields the
+    same counter totals."""
     from hypothesis import given, settings
     from hypothesis import strategies as st
 

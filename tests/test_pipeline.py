@@ -21,6 +21,7 @@ def run_sub(body, devices=8, timeout=600):
 def test_pipeline_matches_sequential():
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_test_mesh
         from repro.distributed.pipeline import pipeline_apply, split_stage_params
 
         L, D = 8, 32
@@ -43,7 +44,7 @@ def test_pipeline_matches_sequential():
         ref = jax.vmap(lambda mb: stage_fn(params, mb))(x)
 
         for n_stages in (2, 4):
-            mesh = jax.make_mesh((n_stages, 8 // n_stages), ("pod", "data"))
+            mesh = make_test_mesh((n_stages, 8 // n_stages), ("pod", "data"))
             sp = split_stage_params(params, n_stages)
             out = pipeline_apply(stage_fn, sp, x, mesh=mesh, axis="pod")
             np.testing.assert_allclose(np.asarray(out), np.asarray(ref),

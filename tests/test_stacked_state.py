@@ -37,6 +37,7 @@ from repro.core.coap_adafactor import (
 )
 from repro.core.projector import ProjectionRules
 from repro.train import checkpoint as ckpt
+from repro.launch.mesh import make_test_mesh
 
 sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -46,6 +47,17 @@ sys.path.insert(
 def _cfg(**kw):
     kw.setdefault("rules", ProjectionRules(rank=16, min_dim=8))
     return ProjectedAdamConfig(**kw)
+
+
+def assert_equal_to_fp32_rounding(a, b):
+    """Equal up to fp32 rounding, within two ulps of the leaf's largest
+    magnitude. The two storage layouts compile to different programs, and
+    XLA may fuse a reduction differently in each, so one sum can round
+    differently; the math is the same."""
+    a = np.asarray(a, np.float64)
+    b = np.asarray(b, np.float64)
+    tol = 2 * np.finfo(np.float32).eps * max(float(np.max(np.abs(b), initial=0.0)), 1e-30)
+    np.testing.assert_allclose(a, b, rtol=0, atol=tol)
 
 
 def _params():
@@ -229,7 +241,8 @@ def test_stacked_bf16_gradient_streaming_parity():
 
 def test_stacked_adafactor_matches_per_leaf_bitwise():
     """The adafactor variant computes per-leaf through leaf_view slices, so
-    stacked and per-leaf modes are bit-identical there."""
+    stacked and per-leaf modes run the same math, equal to fp32 rounding
+    (XLA may order a reduction differently in the two programs)."""
     params = _params()
     g = _grads(params, seed=7)
     treedef = jax.tree_util.tree_structure(params)
@@ -247,7 +260,7 @@ def test_stacked_adafactor_matches_per_leaf_bitwise():
         outs[stacked] = (upd, _as_perleaf_tree(state.leaves, treedef))
     for a, b in zip(jax.tree_util.tree_leaves(outs[True]),
                     jax.tree_util.tree_leaves(outs[False])):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert_equal_to_fp32_rounding(a, b)
 
 
 # ---------------------------------------------------------------------------
@@ -342,14 +355,13 @@ def test_compressed_update_stacked_matches_per_leaf():
     """compressed_update on stacked state (leaf_view addressing) must match
     the per-leaf state path — same jnp reduction schedule, state layout
     only differs (floats to XLA-fusion ulp, the A/B standard)."""
-    from repro import compat
     from repro.distributed.compression import compressed_update
 
     params = {f"a{i}": {"w": jnp.zeros((96, 64))} for i in range(3)}
     params["bias"] = jnp.zeros((16,))
     g = _grads(params, seed=2)
     treedef = jax.tree_util.tree_structure(params)
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_test_mesh((1,), ("pod",))
     outs = {}
     for stacked in (True, False):
         cfg = _cfg(t_update=2, lam=2, use_fused_kernel=False,
@@ -362,7 +374,7 @@ def test_compressed_update_stacked_matches_per_leaf():
 
         from jax.sharding import PartitionSpec as P
 
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             per_pod, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
             check_vma=False, axis_names={"pod"},
         )
