@@ -753,7 +753,8 @@ def scale_by_projected_adam(cfg: ProjectedAdamConfig) -> GradientTransformation:
         ``gc`` keeps the gradient's dtype — bf16 gradients stream into the
         fused kernels as bf16 (upcast per-tile in VMEM, halving per-step G
         traffic); only the unfused jnp fallbacks materialize fp32."""
-        gc = projector.to_canonical(g, spec)
+        with jax.named_scope("update"):  # the kernel's layout, m >= n
+            gc = projector.to_canonical(g, spec)
         p_old = leaf.p
 
         # Loader takes an optional bucket-axis slice so staggered group
@@ -767,19 +768,22 @@ def scale_by_projected_adam(cfg: ProjectedAdamConfig) -> GradientTransformation:
             def m_loader(sl=slice(None)):
                 return leaf.m[sl].astype(jnp.float32)
 
-        new_p, refreshed = _refresh_p(
-            cfg, spec, p_old, gc, m_loader, count, idx_arr, phases
-        )
+        # Eqn 6 / Eqn 7 and any moment transplant run under the bucket's
+        # "refresh" scope, the per-step kernel under "update" (op_name).
+        with jax.named_scope("refresh"):
+            new_p, refreshed = _refresh_p(
+                cfg, spec, p_old, gc, m_loader, count, idx_arr, phases
+            )
 
-        # Projection-health emit (obs/health): refresh-boundary metrics
-        # computed where G is already materialized, under the same
-        # lax.cond as the refresh — non-refresh steps execute nothing, so
-        # the hot path keeps zero extra G round-trips. Trace-time no-op
-        # (identical compiled program) when no monitor is configured.
-        health.emit_refresh_matrix(
-            health.bucket_label("project", g.shape[1:], g.dtype),
-            gc, p_old, new_p, refreshed, count,
-        )
+            # Projection-health emit (obs/health): refresh-boundary metrics
+            # computed where G is already materialized, under the same
+            # lax.cond as the refresh — non-refresh steps execute nothing, so
+            # the hot path keeps zero extra G round-trips. Trace-time no-op
+            # (identical compiled program) when no monitor is configured.
+            health.emit_refresh_matrix(
+                health.bucket_label("project", g.shape[1:], g.dtype),
+                gc, p_old, new_p, refreshed, count,
+            )
 
         if cfg.quantize:
             m_q, m_s = leaf.m, leaf.m_scale
@@ -811,72 +815,77 @@ def scale_by_projected_adam(cfg: ProjectedAdamConfig) -> GradientTransformation:
                     )
 
                 tgroups = _phase_groups(phases) if phases else []
-                if len(tgroups) <= 1:
-                    def transplanted():
-                        cq, cs = carry_q(slice(None))
-                        return (
-                            jnp.where(
-                                _expand_mask(refreshed, cq.ndim), cq, m_q
-                            ),
-                            jnp.where(
-                                _expand_mask(refreshed, cs.ndim), cs, m_s
-                            ),
-                        )
+                with jax.named_scope("refresh"):
+                    if len(tgroups) <= 1:
+                        def transplanted():
+                            cq, cs = carry_q(slice(None))
+                            return (
+                                jnp.where(
+                                    _expand_mask(refreshed, cq.ndim), cq, m_q
+                                ),
+                                jnp.where(
+                                    _expand_mask(refreshed, cs.ndim), cs, m_s
+                                ),
+                            )
 
-                    m_q, m_s = lax.cond(
-                        jnp.any(refreshed), transplanted, lambda: (m_q, m_s)
+                        m_q, m_s = lax.cond(
+                            jnp.any(refreshed), transplanted, lambda: (m_q, m_s)
+                        )
+                    else:
+                        m_q, m_s = _stagger_dispatch(
+                            tgroups, count, cfg.t_update,
+                            noop=lambda: (m_q, m_s),
+                            group_fn=q_group,
+                            full_fn=lambda: carry_q(slice(None)),  # t=0 init
+                        )
+            with jax.named_scope("update"):
+                if cfg.use_fused_kernel:
+                    # Single-pass fused int8 step: no fp32 M/V, no Δ_proj in HBM.
+                    nmq, nms, nvq, nvs, update_c = kops.coap_fused_update_q8(
+                        gc, new_p, m_q, m_s, leaf.v, leaf.v_scale, t,
+                        b1=cfg.b1, b2=cfg.b2, eps=cfg.eps, block=cfg.quant_block,
                     )
                 else:
-                    m_q, m_s = _stagger_dispatch(
-                        tgroups, count, cfg.t_update,
-                        noop=lambda: (m_q, m_s),
-                        group_fn=q_group,
-                        full_fn=lambda: carry_q(slice(None)),  # t=0 init
+                    # Unfused 8-bit schedule — every intermediate round-trips
+                    # HBM; kept as the benchmark baseline (benchmarks/overhead).
+                    # The oracle IS that schedule expressed as jnp ops.
+                    nmq, nms, nvq, nvs, update_c = kref.coap_fused_update_q8(
+                        gc, new_p, m_q, m_s, leaf.v, leaf.v_scale, t,
+                        b1=cfg.b1, b2=cfg.b2, eps=cfg.eps, block=cfg.quant_block,
                     )
-            if cfg.use_fused_kernel:
-                # Single-pass fused int8 step: no fp32 M/V, no Δ_proj in HBM.
-                nmq, nms, nvq, nvs, update_c = kops.coap_fused_update_q8(
-                    gc, new_p, m_q, m_s, leaf.v, leaf.v_scale, t,
-                    b1=cfg.b1, b2=cfg.b2, eps=cfg.eps, block=cfg.quant_block,
-                )
-            else:
-                # Unfused 8-bit schedule — every intermediate round-trips
-                # HBM; kept as the benchmark baseline (benchmarks/overhead).
-                # The oracle IS that schedule expressed as jnp ops.
-                nmq, nms, nvq, nvs, update_c = kref.coap_fused_update_q8(
-                    gc, new_p, m_q, m_s, leaf.v, leaf.v_scale, t,
-                    b1=cfg.b1, b2=cfg.b2, eps=cfg.eps, block=cfg.quant_block,
-                )
-            new_leaf = ProjLeaf(p=new_p, m=nmq, v=nvq, m_scale=nms,
-                                v_scale=nvs, ef=leaf.ef)
+                new_leaf = ProjLeaf(p=new_p, m=nmq, v=nvq, m_scale=nms,
+                                    v_scale=nvs, ef=leaf.ef)
         else:
             m = m_loader()
             v = leaf.v.astype(jnp.float32)
-            m = _maybe_transplant(
-                cfg, m, p_old, new_p, refreshed, phases, count
-            )
-            if cfg.use_fused_kernel:
-                new_m, new_v, update_c = kops.coap_fused_update_bp(
-                    gc, new_p, m, v, t, b1=cfg.b1, b2=cfg.b2, eps=cfg.eps
+            with jax.named_scope("refresh"):
+                m = _maybe_transplant(
+                    cfg, m, p_old, new_p, refreshed, phases, count
                 )
-            else:
-                g_proj = projector.project(gc.astype(jnp.float32), new_p)
-                new_m = cfg.b1 * m + (1.0 - cfg.b1) * g_proj
-                new_v = cfg.b2 * v + (1.0 - cfg.b2) * jnp.square(g_proj)
-                tf = t.astype(jnp.float32)
-                delta_proj = (new_m / (1.0 - cfg.b1**tf)) / (
-                    jnp.sqrt(new_v / (1.0 - cfg.b2**tf)) + cfg.eps
+            with jax.named_scope("update"):
+                if cfg.use_fused_kernel:
+                    new_m, new_v, update_c = kops.coap_fused_update_bp(
+                        gc, new_p, m, v, t, b1=cfg.b1, b2=cfg.b2, eps=cfg.eps
+                    )
+                else:
+                    g_proj = projector.project(gc.astype(jnp.float32), new_p)
+                    new_m = cfg.b1 * m + (1.0 - cfg.b1) * g_proj
+                    new_v = cfg.b2 * v + (1.0 - cfg.b2) * jnp.square(g_proj)
+                    tf = t.astype(jnp.float32)
+                    delta_proj = (new_m / (1.0 - cfg.b1**tf)) / (
+                        jnp.sqrt(new_v / (1.0 - cfg.b2**tf)) + cfg.eps
+                    )
+                    update_c = projector.backproject(delta_proj, new_p)
+                new_leaf = ProjLeaf(
+                    p=new_p,
+                    m=new_m.astype(cfg.state_dtype),
+                    v=new_v.astype(cfg.state_dtype),
+                    m_scale=leaf.m_scale,  # fp32 placeholders pass through
+                    v_scale=leaf.v_scale,
+                    ef=leaf.ef,
                 )
-                update_c = projector.backproject(delta_proj, new_p)
-            new_leaf = ProjLeaf(
-                p=new_p,
-                m=new_m.astype(cfg.state_dtype),
-                v=new_v.astype(cfg.state_dtype),
-                m_scale=leaf.m_scale,  # fp32 placeholders pass through
-                v_scale=leaf.v_scale,
-                ef=leaf.ef,
-            )
-        update = projector.from_canonical(update_c, spec) * cfg.update_scale
+        with jax.named_scope("update"):
+            update = projector.from_canonical(update_c, spec) * cfg.update_scale
         return update.astype(g.dtype), new_leaf
 
     def _update_dense_leaf(cfg, leaf: DenseLeaf, g, count, t):
@@ -978,42 +987,53 @@ def scale_by_projected_adam(cfg: ProjectedAdamConfig) -> GradientTransformation:
                 slot_groups = [tuple(range(len(info.indices)))]
             else:  # per-leaf A/B mode (stacked_state forbids this)
                 slot_groups = [(k,) for k in range(len(info.indices))]
-            for slots in slot_groups:
-                idxs = [info.indices[k] for k in slots]
-                g_stack = jnp.stack([flat_u[i][1] for i in idxs])
-                if cfg.stacked_state:
-                    # The hot-path win: the bucket state is ALREADY stacked
-                    # — no stack copy in, no scatter copy out.
-                    leaf_stack = prev.buckets[bi]
-                else:
-                    leaf_stack = jax.tree_util.tree_map(
-                        lambda *xs: jnp.stack(xs),
-                        *[flat_s[i] for i in idxs],
-                    )
-                if is_proj:
-                    u_stack, nl_stack = _update_proj_bucket(
-                        bcfg, leaf_stack, g_stack, info.spec, count, t,
-                        jnp.asarray(idxs, jnp.int32),
-                        tuple(phases[k] for k in slots),
-                    )
-                elif is_conv:
-                    u_stack, nl_stack = conv_mod.update_conv_bucket(
-                        bcfg, leaf_stack, g_stack, info.spec, count, t,
-                        jnp.asarray(idxs, jnp.int32),
-                        tuple(phases[k] for k in slots),
-                    )
-                else:
-                    u_stack, nl_stack = jax.vmap(
-                        lambda lf, gg: _update_dense_leaf(bcfg, lf, gg, count, t)
-                    )(leaf_stack, g_stack)
-                for b, i in enumerate(idxs):
-                    new_updates[i] = u_stack[b]
-                    if not cfg.stacked_state:
-                        new_flat[i] = jax.tree_util.tree_map(
-                            lambda x: x[b], nl_stack
+            # One scope per bucket in the step's op_names, health's label
+            # for it ("dense/" before a dense Adam bucket's), and inside it
+            # gather / refresh / update / scatter.
+            label = health.bucket_label(info.kind, info.shape, info.dtype)
+            if not (is_proj or is_conv):
+                label = "dense/" + label
+            with jax.named_scope(label):
+                for slots in slot_groups:
+                    idxs = [info.indices[k] for k in slots]
+                    with jax.named_scope("gather"):
+                        g_stack = jnp.stack([flat_u[i][1] for i in idxs])
+                        if cfg.stacked_state:
+                            # The hot-path win: the bucket state is ALREADY
+                            # stacked — no stack copy in, no scatter copy out.
+                            leaf_stack = prev.buckets[bi]
+                        else:
+                            leaf_stack = jax.tree_util.tree_map(
+                                lambda *xs: jnp.stack(xs),
+                                *[flat_s[i] for i in idxs],
+                            )
+                    if is_proj:
+                        u_stack, nl_stack = _update_proj_bucket(
+                            bcfg, leaf_stack, g_stack, info.spec, count, t,
+                            jnp.asarray(idxs, jnp.int32),
+                            tuple(phases[k] for k in slots),
                         )
-                if cfg.stacked_state:
-                    new_buckets[bi] = nl_stack
+                    elif is_conv:
+                        u_stack, nl_stack = conv_mod.update_conv_bucket(
+                            bcfg, leaf_stack, g_stack, info.spec, count, t,
+                            jnp.asarray(idxs, jnp.int32),
+                            tuple(phases[k] for k in slots),
+                        )
+                    else:
+                        with jax.named_scope("update"):
+                            u_stack, nl_stack = jax.vmap(
+                                lambda lf, gg: _update_dense_leaf(
+                                    bcfg, lf, gg, count, t)
+                            )(leaf_stack, g_stack)
+                    with jax.named_scope("scatter"):
+                        for b, i in enumerate(idxs):
+                            new_updates[i] = u_stack[b]
+                            if not cfg.stacked_state:
+                                new_flat[i] = jax.tree_util.tree_map(
+                                    lambda x: x[b], nl_stack
+                                )
+                    if cfg.stacked_state:
+                        new_buckets[bi] = nl_stack
 
         if cfg.stacked_state:
             new_leaves = stacked_state.StackedLeaves(

@@ -268,7 +268,8 @@ def coap_fused_update_pallas(
     if not interpret:
         kwargs["compiler_params"] = two_phase_compiler_params()
 
-    new_m, new_v, delta = pl.pallas_call(kernel, **kwargs)(
+    new_m, new_v, delta = pl.pallas_call(
+        kernel, name="coap_fused_update_pallas", **kwargs)(
         corr, g_p, p_p, m_p, v_p
     )
     return new_m[:m_dim], new_v[:m_dim], delta[:m_dim]
@@ -338,7 +339,8 @@ def coap_fused_update_bp_pallas(
     if not interpret:
         kwargs["compiler_params"] = two_phase_compiler_params()
 
-    new_m, new_v, dw = pl.pallas_call(kernel, **kwargs)(
+    new_m, new_v, dw = pl.pallas_call(
+        kernel, name="coap_fused_update_bp_pallas", **kwargs)(
         corr, g_p, p_p, m_p, v_p
     )
     return new_m[:m_dim], new_v[:m_dim], dw[:m_dim, :n_dim]

@@ -338,7 +338,8 @@ def eqn6_sgd_update_pallas(
     if not interpret:
         kwargs["compiler_params"] = _sequential_compiler_params()
 
-    p_new, val, grad = pl.pallas_call(kernel, **kwargs)(p_p, g_p, mp_p)
+    p_new, val, grad = pl.pallas_call(
+        kernel, name="eqn6_sgd_update_pallas", **kwargs)(p_p, g_p, mp_p)
     return (
         p_new[:n_dim, :r].astype(p.dtype),
         val[0, 0],

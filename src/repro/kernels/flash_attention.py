@@ -240,7 +240,8 @@ def _fwd(q, k, v, scale, causal, window, softcap, qb, kb, interpret):
         pltpu.VMEM((qb_e, 1), jnp.float32),
         pltpu.VMEM((qb_e, hd), jnp.float32),
     ]
-    o, lse = pl.pallas_call(kernel, **kwargs)(qp, kp, vp)
+    o, lse = pl.pallas_call(kernel, name="flash_attention_fwd", **kwargs)(
+        qp, kp, vp)
     return o[:, :t], (q, k, v, o[:, :t], lse[:, :t])
 
 
@@ -296,7 +297,8 @@ def _bwd_rule(scale, causal, window, softcap, qb, kb, interpret, res, do):
         pltpu.VMEM((kb_e, hd), jnp.float32),
         pltpu.VMEM((kb_e, hd), jnp.float32),
     ]
-    dk_per_qh, dv_per_qh = pl.pallas_call(dkv_kernel, **kwargs)(
+    dk_per_qh, dv_per_qh = pl.pallas_call(
+        dkv_kernel, name="flash_attention_dkv", **kwargs)(
         qp, kp, vp, dop, lse_p, delta_p
     )
     dk = dk_per_qh.reshape(bkh, group, sp, hd).sum(axis=1)[:, :s]
@@ -322,7 +324,7 @@ def _bwd_rule(scale, causal, window, softcap, qb, kb, interpret, res, do):
         **_pallas_kwargs(interpret, ("parallel", "parallel", "arbitrary")),
     )
     kwargs["scratch_shapes"] = [pltpu.VMEM((qb_e, hd), jnp.float32)]
-    dq = pl.pallas_call(dq_kernel, **kwargs)(
+    dq = pl.pallas_call(dq_kernel, name="flash_attention_dq", **kwargs)(
         qp, kp, vp, dop, lse_p, delta_p
     )[:, :t]
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)

@@ -131,6 +131,7 @@ def quantize_blockwise_pallas(x, block=QUANT_BLOCK, interpret=False):
     grid = (bp.shape[0] // rows,)
     q, s = pl.pallas_call(
         _quant_kernel,
+        name="quantize_blockwise_pallas",
         grid=grid,
         in_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0))],
         out_specs=[
@@ -156,6 +157,7 @@ def dequantize_blockwise_pallas(q, scale, shape, dtype=jnp.float32,
     grid = (qp.shape[0] // rows,)
     x = pl.pallas_call(
         _dequant_kernel,
+        name="dequantize_blockwise_pallas",
         grid=grid,
         in_specs=[
             pl.BlockSpec((rows, block), lambda i: (i, 0)),
@@ -195,6 +197,7 @@ def quantized_adam_update_pallas(
     npad = gp.shape[0]
     nmq, nms, nvq, nvs, delta = pl.pallas_call(
         functools.partial(_fused8_kernel, b1=b1, b2=b2, eps=eps),
+        name="quantized_adam_update_pallas",
         grid=grid,
         in_specs=[pl.BlockSpec((2,), lambda i: (0,)), row_spec, row_spec,
                   s_spec, row_spec, s_spec],
@@ -366,7 +369,8 @@ def coap_fused_update_q8_pallas(
     if not interpret:
         kwargs["compiler_params"] = two_phase_compiler_params()
 
-    nmq, nms, nvq, nvs, dw = pl.pallas_call(kernel, **kwargs)(
+    nmq, nms, nvq, nvs, dw = pl.pallas_call(
+        kernel, name="coap_fused_update_q8_pallas", **kwargs)(
         corr, g_p, p_p, mq_p, ms_p, vq_p, vs_p
     )
     return (

@@ -36,6 +36,7 @@ def rmsnorm_pallas(x, scale, eps=1e-6, interpret=False, bm=256):
     grid = (x2.shape[0] // bm_eff,)
     out = pl.pallas_call(
         functools.partial(_kernel, eps=eps),
+        name="rmsnorm_pallas",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm_eff, d), lambda i: (i, 0)),

@@ -72,25 +72,27 @@ class LMModel:
         so big-vocab logits never materialize wholesale."""
         cfg = self.cfg
         L.set_pure_bf16(cfg.bf16_elementwise)
-        h = self._embed_in(params, batch)
+        with jax.named_scope("embed"):
+            h = self._embed_in(params, batch)
         h = shd.constrain(h, ("batch", "seq_data" if h.shape[0] == 1 else None, None))
         positions = self._positions(batch, h)
-        if cfg.family == "audio":
-            if "enc_embeds" in batch:  # train / prefill: run the encoder
-                enc_out = T.encoder_apply(cfg, params["stack"],
-                                          batch["enc_embeds"].astype(cfg.dtype))
-            else:  # decode: reuse the cached encoder output
-                enc_out = caches["enc_out"]
-            dec_caches = caches["kv"] if caches is not None else None
-            h, new_kv, aux = T.decoder_apply(cfg, params["stack"], h, positions,
-                                             enc_out, dec_caches)
-            new_caches = {"kv": new_kv, "enc_out": enc_out} if caches is not None else None
-        elif cfg.family == "hybrid":
-            h, new_caches, aux = T.hybrid_apply(cfg, params["stack"], h,
-                                                positions, caches)
-        else:
-            h, new_caches, aux = T.uniform_stack_apply(cfg, params["stack"], h,
-                                                       positions, caches)
+        with jax.named_scope("stack"):
+            if cfg.family == "audio":
+                if "enc_embeds" in batch:  # train / prefill: run the encoder
+                    enc_out = T.encoder_apply(cfg, params["stack"],
+                                              batch["enc_embeds"].astype(cfg.dtype))
+                else:  # decode: reuse the cached encoder output
+                    enc_out = caches["enc_out"]
+                dec_caches = caches["kv"] if caches is not None else None
+                h, new_kv, aux = T.decoder_apply(cfg, params["stack"], h, positions,
+                                                 enc_out, dec_caches)
+                new_caches = {"kv": new_kv, "enc_out": enc_out} if caches is not None else None
+            elif cfg.family == "hybrid":
+                h, new_caches, aux = T.hybrid_apply(cfg, params["stack"], h,
+                                                    positions, caches)
+            else:
+                h, new_caches, aux = T.uniform_stack_apply(cfg, params["stack"], h,
+                                                           positions, caches)
         h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps)
         return h, new_caches, aux
 
@@ -136,7 +138,9 @@ class LMModel:
             return carry + jnp.sum(ce), jnp.sum(valid)
 
         body = jax.checkpoint(chunk_loss)
-        total, counts = jax.lax.scan(body, jnp.zeros([], jnp.float32), (hc, yc))
+        with jax.named_scope("head"):
+            total, counts = jax.lax.scan(body, jnp.zeros([], jnp.float32),
+                                         (hc, yc))
         n_valid = jnp.maximum(jnp.sum(counts), 1)
         ce = total / n_valid
         loss = ce + 0.01 * aux

@@ -14,9 +14,13 @@ JSON object per line — the same torn-write-tolerant journal format as
 ``depth`` fields rather than file order, so interleaved threads and
 worker restarts append safely to one file.
 
-Disabled (no path configured) tracing costs one attribute load and a
-truthiness check per ``span()`` call — ``span()`` returns a shared no-op
-context manager, no allocation, no clock read. That is what the
+Every span is also a ``jax.profiler.TraceAnnotation`` of the same name
+once the process has imported jax (this module never imports it), with
+or without a path: a profiler session then shows the span as a host
+event on the profiler's clock, beside the device's operations. With no
+profiler session and no path a span costs about 1 µs (the annotation's
+construction; no row, no clock read); before jax is imported ``span()``
+returns a shared no-op context manager. That is what the
 ``benchmarks/overhead.run_obs`` <3% hot-path gate certifies.
 
 Export: :func:`export_perfetto` converts a trace.jsonl into the Chrome
@@ -32,6 +36,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -54,14 +59,37 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+_ANNOTATION = None  # set by _annotation() once jax is imported
+
+
+def _annotation():
+    """The class of a profiler-only span: ``jax.profiler.TraceAnnotation``
+    with the span's ``set``. None while the process has not imported jax."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
+            return None
+
+        class _Annotation(profiler.TraceAnnotation):
+            __slots__ = ()
+
+            def set(self, **attrs) -> None:
+                return None
+
+        _ANNOTATION = _Annotation
+    return _ANNOTATION
+
 
 class _Span:
-    __slots__ = ("tracer", "name", "attrs", "t0", "parent", "depth")
+    __slots__ = ("tracer", "name", "attrs", "t0", "parent", "depth", "annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
+    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any],
+                 annotation=None):
         self.tracer = tracer
         self.name = name
         self.attrs = attrs
+        self.annotation = annotation
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
@@ -71,11 +99,15 @@ class _Span:
         self.parent = stack[-1].name if stack else None
         self.depth = len(stack)
         stack.append(self)
+        if self.annotation is not None:
+            self.annotation.__enter__()
         self.t0 = time.time()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         dur = time.time() - self.t0
+        if self.annotation is not None:
+            self.annotation.__exit__(exc_type, exc, tb)
         stack = self.tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -100,7 +132,8 @@ class _Span:
 class Tracer:
     """Appends span/instant rows to one jsonl file; thread-safe (a lock
     serializes writes, a ``threading.local`` stack tracks nesting per
-    thread). With ``path=None`` the tracer is disabled and near-free."""
+    thread). With ``path=None`` the tracer writes nothing and its spans
+    are profiler annotations alone."""
 
     def __init__(self, path: Optional[str] = None,
                  host: Optional[str] = None):
@@ -136,10 +169,13 @@ class Tracer:
 
     # -- the API -------------------------------------------------------------
     def span(self, name: str, **attrs):
-        """A timed context manager; the row is written on exit."""
+        """A timed context manager; the row is written on exit. It is also
+        a profiler annotation of ``name`` once jax is imported."""
+        annotation = _ANNOTATION or _annotation()
         if self._f is None:
-            return _NULL_SPAN
-        return _Span(self, name, attrs)
+            return _NULL_SPAN if annotation is None else annotation(name)
+        return _Span(self, name, attrs,
+                     None if annotation is None else annotation(name))
 
     def instant(self, name: str, **attrs) -> None:
         """A point event (``ph: "i"``) — decisions, faults, commits."""

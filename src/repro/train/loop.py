@@ -109,6 +109,13 @@ class TrainLoop:
         params = self.model.init(self._init_key)
         return TrainState.create(params, self.tx)
 
+    def step_hlo_text(self, state, batch) -> str:
+        """The optimized HLO text of the step compiled for these arguments
+        (arrays or ``jax.ShapeDtypeStruct`` trees): its instruction names
+        are the operations of a device trace, and each carries its
+        ``op_name`` scope path (``model``, ``optimizer``, ...)."""
+        return self._step_fn.lower(state, batch).compile().as_text()
+
     # -- drain ---------------------------------------------------------------
     def _notice_deadline(self, step: int) -> Optional[float]:
         """An active preemption notice's absolute deadline, or None. File
@@ -166,80 +173,92 @@ class TrainLoop:
         ceu_total = 0.0
         inj = cfg.fault_injector
         for step in range(start, cfg.total_steps):
-            deadline = self._notice_deadline(step)
-            if deadline is not None:
-                self._drain(state, step, deadline)
-            if cfg.crash_at_step is not None and step == cfg.crash_at_step:
-                raise RuntimeError(f"induced crash at step {step}")
-            if inj is not None:
-                inj.maybe_kill(step)
-            batch = self.batch_fn(step, 0)
-            # Refresh-group attribution is computed host-side BEFORE the
-            # step (a pure function of (plan, step)) so the span carries
-            # exactly what the jitted update is about to do.
-            span_attrs = {"step": step}
-            if cfg.refresh_schedule is not None:
-                ev = cfg.refresh_schedule(step)
-                if ev:
-                    span_attrs["refresh"] = ev
-            if step == start:
-                # First execution of this loop instance traces + compiles.
-                span_attrs["compile"] = True
-            t0 = time.time()
-            with tracer.span("loop/step", **span_attrs):
-                state, metrics = self._step_fn(state, batch)
-                jax.block_until_ready(state.params)
-            dt = time.time() - t0
-            if cfg.min_step_s > 0 and dt < cfg.min_step_s:
-                time.sleep(cfg.min_step_s - dt)
-            if inj is not None:
-                dt += inj.slow_delay(step)
-            slow = self.straggler.observe(dt)
-            if slow:
-                reg.inc("loop/straggler_step")
-            ceu_total += float(metrics["ceu"])
-            if (
-                cfg.health_every
-                and health.get_monitor().enabled
-                and step % cfg.health_every == 0
-            ):
-                health.observe_state(state.opt_state, step)
-            if self.heartbeat and not (
-                inj is not None and inj.heartbeat_silent(step)
-            ):
-                snap = reg.snapshot()
-                self.heartbeat.beat(
-                    step,
-                    extra={
-                        "straggler_flagged": self.straggler.flagged,
-                        "phase": reg.gauge("phase", "train"),
-                        # The registry snapshot rides every beat: the
-                        # supervisor (and fleet_status) reads a worker's
-                        # counters AND health gauges with no extra channel.
-                        "counters": snap["counters"],
-                        "gauges": snap["gauges"],
-                    },
-                )
-            if step % cfg.log_every == 0 or step == cfg.total_steps - 1:
-                row = dict(metrics)
-                row["ceu_total"] = ceu_total
-                row["straggler"] = int(slow)
-                ntok = 0
-                b = batch.get("tokens", batch.get("embeds"))
-                if b is not None:
-                    ntok = b.shape[0] * b.shape[1]
-                self.logger.log(step, row, tokens=ntok)
-            if (
-                cfg.ckpt_dir
-                and cfg.ckpt_every
-                and (step + 1) % cfg.ckpt_every == 0
-            ):
-                with tracer.span("loop/checkpoint", step=step + 1):
-                    ckpt.save(cfg.ckpt_dir, step + 1, state,
-                              keep=cfg.ckpt_keep, meta=cfg.ckpt_meta)
-                reg.inc("ckpt/save")
+            # One profiler step per iteration; the loop's own spans below
+            # are host events inside it (obs/trace.py).
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                deadline = self._notice_deadline(step)
+                if deadline is not None:
+                    self._drain(state, step, deadline)
+                if cfg.crash_at_step is not None and step == cfg.crash_at_step:
+                    raise RuntimeError(f"induced crash at step {step}")
                 if inj is not None:
-                    inj.after_save(cfg.ckpt_dir, step + 1)
+                    inj.maybe_kill(step)
+                with tracer.span("loop/batch"):
+                    batch = self.batch_fn(step, 0)
+                # Refresh-group attribution is computed host-side BEFORE the
+                # step (a pure function of (plan, step)) so the span carries
+                # exactly what the jitted update is about to do.
+                span_attrs = {"step": step}
+                if cfg.refresh_schedule is not None:
+                    ev = cfg.refresh_schedule(step)
+                    if ev:
+                        span_attrs["refresh"] = ev
+                if step == start:
+                    # First execution of this loop instance traces + compiles.
+                    span_attrs["compile"] = True
+                t0 = time.time()
+                # loop/step spans the dispatch and the wait, as it always has
+                # (obs/calib reads it); the two children split it.
+                with tracer.span("loop/step", **span_attrs):
+                    with tracer.span("loop/dispatch"):
+                        state, metrics = self._step_fn(state, batch)
+                    with tracer.span("loop/wait"):
+                        jax.block_until_ready(state.params)
+                dt = time.time() - t0
+                if cfg.min_step_s > 0 and dt < cfg.min_step_s:
+                    time.sleep(cfg.min_step_s - dt)
+                if inj is not None:
+                    dt += inj.slow_delay(step)
+                slow = self.straggler.observe(dt)
+                if slow:
+                    reg.inc("loop/straggler_step")
+                with tracer.span("loop/metrics_pull"):
+                    ceu_total += float(metrics["ceu"])
+                if (
+                    cfg.health_every
+                    and health.get_monitor().enabled
+                    and step % cfg.health_every == 0
+                ):
+                    health.observe_state(state.opt_state, step)
+                if self.heartbeat and not (
+                    inj is not None and inj.heartbeat_silent(step)
+                ):
+                    with tracer.span("loop/heartbeat"):
+                        snap = reg.snapshot()
+                        self.heartbeat.beat(
+                            step,
+                            extra={
+                                "straggler_flagged": self.straggler.flagged,
+                                "phase": reg.gauge("phase", "train"),
+                                # The registry snapshot rides every beat:
+                                # the supervisor (and fleet_status) reads a
+                                # worker's counters AND health gauges with
+                                # no extra channel.
+                                "counters": snap["counters"],
+                                "gauges": snap["gauges"],
+                            },
+                        )
+                if step % cfg.log_every == 0 or step == cfg.total_steps - 1:
+                    with tracer.span("loop/log"):
+                        row = dict(metrics)
+                        row["ceu_total"] = ceu_total
+                        row["straggler"] = int(slow)
+                        ntok = 0
+                        b = batch.get("tokens", batch.get("embeds"))
+                        if b is not None:
+                            ntok = b.shape[0] * b.shape[1]
+                        self.logger.log(step, row, tokens=ntok)
+                if (
+                    cfg.ckpt_dir
+                    and cfg.ckpt_every
+                    and (step + 1) % cfg.ckpt_every == 0
+                ):
+                    with tracer.span("loop/checkpoint", step=step + 1):
+                        ckpt.save(cfg.ckpt_dir, step + 1, state,
+                                  keep=cfg.ckpt_keep, meta=cfg.ckpt_meta)
+                    reg.inc("ckpt/save")
+                    if inj is not None:
+                        inj.after_save(cfg.ckpt_dir, step + 1)
         if cfg.ckpt_dir:
             with tracer.span("loop/checkpoint", step=int(state.step),
                              reason="final"):

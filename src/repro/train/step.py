@@ -26,7 +26,10 @@ def make_train_step(
     """
 
     def loss_fn(params, batch):
-        loss, metrics = model.loss(params, batch)
+        # The backward pass of these operations is named
+        # "transpose(jvp(model))/..." in the compiled step's op_name.
+        with jax.named_scope("model"):
+            loss, metrics = model.loss(params, batch)
         return loss, metrics
 
     def compute_grads(params, batch):
@@ -66,19 +69,21 @@ def make_train_step(
 
     def step(state: TrainState, batch) -> tuple:
         loss, metrics, grads = compute_grads(state.params, batch)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = apply_updates(state.params, updates)
-        gnorm = jnp.sqrt(
-            sum(
-                jnp.sum(jnp.square(g.astype(jnp.float32)))
-                for g in jax.tree_util.tree_leaves(grads)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state, state.params)
+            params = apply_updates(state.params, updates)
+        with jax.named_scope("step_metrics"):
+            gnorm = jnp.sqrt(
+                sum(
+                    jnp.sum(jnp.square(g.astype(jnp.float32)))
+                    for g in jax.tree_util.tree_leaves(grads)
+                )
             )
-        )
-        # CEU (paper Fig 3): Σ‖ΔW‖₁ of the applied update
-        ceu = sum(
-            jnp.sum(jnp.abs(u.astype(jnp.float32)))
-            for u in jax.tree_util.tree_leaves(updates)
-        )
+            # CEU (paper Fig 3): Σ‖ΔW‖₁ of the applied update
+            ceu = sum(
+                jnp.sum(jnp.abs(u.astype(jnp.float32)))
+                for u in jax.tree_util.tree_leaves(updates)
+            )
         out_metrics = {"loss": loss, "grad_norm": gnorm, "ceu": ceu}
         for k, v in metrics.items():
             out_metrics.setdefault(k, v)

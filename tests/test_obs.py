@@ -5,8 +5,9 @@ Five layers, matching the ``obs/`` contract:
 
   * **tracer** — nested spans round-trip through trace.jsonl with
     parent/depth recovered per thread, torn lines are skipped, disabled
-    tracing is a shared no-op object, and the Perfetto export is a
-    well-formed Chrome ``trace_event`` document;
+    tracing is a shared no-op object before jax is imported, every span
+    is a host event of a ``jax.profiler`` trace, and the Perfetto export
+    is a well-formed Chrome ``trace_event`` document;
   * **registry** — thread-safe counters/gauges, snapshot tidiness, and
     cross-process merge semantics (counters sum, gauges last-writer-win);
   * **liveness/metrics hardening** — concurrent ``beat``/``touch`` never
@@ -24,6 +25,7 @@ Five layers, matching the ``obs/`` contract:
     artifact the solver consumes, and ``fleet_status --json`` reports the
     same run's phase/step/staleness/counters.
 """
+import glob
 import json
 import os
 import subprocess
@@ -90,15 +92,60 @@ def test_span_nesting_round_trip(tmp_path):
     assert by_name["elastic/replan"]["attrs"]["error"] == "RuntimeError"
 
 
-def test_disabled_tracer_is_shared_noop():
+def test_disabled_tracer_is_shared_noop(tmp_path):
+    # Before jax is imported a disabled span is one shared no-op object: no
+    # allocation. Once jax is imported it is a profiler annotation alone,
+    # and still writes nothing.
+    code = (
+        "import sys\n"
+        "from repro.obs.trace import configure\n"
+        "t = configure(None)\n"
+        "assert not t.enabled\n"
+        "s1, s2 = t.span('a', x=1), t.span('b')\n"
+        "assert s1 is s2\n"
+        "with s1 as sp:\n"
+        "    sp.set(y=2)\n"
+        "t.instant('c')\n"
+        "assert 'jax' not in sys.modules\n"
+    )
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(sys.path))
+    subprocess.run([sys.executable, "-c", code], check=True, env=env,
+                   cwd=tmp_path)
     t = configure(None)
     assert not t.enabled
-    s1 = t.span("a", x=1)
-    s2 = t.span("b")
-    assert s1 is s2  # one shared object: no allocation when disabled
-    with s1 as sp:
+    with t.span("a", x=1) as sp:
         sp.set(y=2)
     t.instant("c")  # no-op, no file
+    assert list(tmp_path.iterdir()) == []
+
+
+@pytest.mark.parametrize("jsonl", [False, True])
+def test_span_is_a_profiler_host_event(tmp_path, jsonl):
+    """Every span lands in a jax.profiler trace as a host event of its
+    name, on the profiler's clock, with or without a trace.jsonl."""
+    path = str(tmp_path / "trace.jsonl") if jsonl else None
+    t = configure(path)
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    with t.span("loop/step", step=1):
+        with t.span("loop/dispatch"):
+            time.sleep(0.002)
+    jax.profiler.stop_trace()
+    (xplane,) = glob.glob(str(tmp_path / "prof" / "plugins" / "profile" / "*"
+                              / "*.xplane.pb"))
+    events = {}
+    for plane in jax.profiler.ProfileData.from_file(xplane).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    events.setdefault(ev.name, ev)
+    assert {"loop/step", "loop/dispatch"} <= set(events)
+    outer, inner = events["loop/step"], events["loop/dispatch"]
+    assert outer.start_ns <= inner.start_ns
+    assert inner.start_ns + inner.duration_ns <= outer.start_ns + outer.duration_ns
+    assert inner.duration_ns >= 2e6
+    rows = read_trace(path) if jsonl else []
+    assert [r["name"] for r in rows] == (["loop/dispatch", "loop/step"]
+                                         if jsonl else [])
 
 
 def test_configure_same_path_appends(tmp_path):

@@ -124,3 +124,75 @@ def test_synthetic_lm_ce_floor_reachable():
     data = SyntheticLM(vocab=32, order=1, noise=0.1)
     floor = data.ce_floor()
     assert 0.1 < floor < np.log(32)
+
+
+def test_step_hlo_names_its_scopes(tmp_path):
+    """The compiled step's op_names carry the scopes a device trace is
+    attributed by: the model and its backward pass, the optimizer with
+    each bucket's gather / refresh / update / scatter, the step metrics."""
+    import re
+
+    loop, model, tx = _setup(str(tmp_path), total_steps=1)
+    state = jax.eval_shape(loop.init_or_restore)
+    batch = jax.eval_shape(lambda: loop.batch_fn(0, 0))
+    names = set(re.findall(r'op_name="([^"]*)"', loop.step_hlo_text(state, batch)))
+    parts = {p for n in names for p in n.split("/")}
+    assert {"jvp(model)", "transpose(jvp(model))", "optimizer", "step_metrics",
+            "embed", "stack", "head"} <= parts
+    subs = {}
+    for n in names:
+        m = re.search(r"/optimizer/(project:[0-9x]+:float32)/(\w+)/", n)
+        if m:
+            subs.setdefault(m.group(1), set()).add(m.group(2))
+    assert any(s >= {"gather", "refresh", "update", "scatter"} for s in subs.values()), subs
+    assert any(re.search(r"/optimizer/dense/dense:[0-9x]+:float32/update/", n)
+               for n in names)
+
+
+def test_step_hlo_text_reads_the_executable_that_ran(tmp_path):
+    """After ``run``, the HLO text for the state it returned and a batch of
+    its feed comes from the jitted step's cache: nothing compiles again."""
+    loop, _, _ = _setup(str(tmp_path), total_steps=2)
+    state = loop.run()
+    compiles = []
+
+    def listen(name, secs, **kw):
+        if name.endswith("backend_compile_duration"):
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    text = loop.step_hlo_text(state, loop.batch_fn(2, 0))
+    assert compiles == []
+    assert "optimizer/" in text and "HloModule jit_step" in text
+
+
+def test_loop_spans_keep_their_fields(tmp_path):
+    """``loop/step`` rows keep their attributes and extent (what obs/calib
+    reads); dispatch and wait nest in it; the feed, the metrics pull, the
+    heartbeat and the log are spans of their own."""
+    from repro.obs.trace import configure, read_trace
+
+    path = str(tmp_path / "trace.jsonl")
+    configure(path)
+    try:
+        loop, _, _ = _setup(str(tmp_path), total_steps=3, log_every=1,
+                            heartbeat_path=str(tmp_path / "hb.json"))
+        loop.run()
+    finally:
+        configure(None)
+    rows = read_trace(path)
+    steps = [r for r in rows if r["name"] == "loop/step"]
+    assert [r["attrs"] for r in steps] == [{"step": 0, "compile": True},
+                                           {"step": 1}, {"step": 2}]
+    assert all(r["depth"] == 0 and r["parent"] is None for r in steps)
+    for child in ("loop/dispatch", "loop/wait"):
+        kids = [r for r in rows if r["name"] == child]
+        assert len(kids) == 3
+        assert all(r["parent"] == "loop/step" and r["depth"] == 1 for r in kids)
+    for step, dispatch, wait in zip(
+            steps, *[[r for r in rows if r["name"] == c]
+                     for c in ("loop/dispatch", "loop/wait")]):
+        assert step["ts"] <= dispatch["ts"] <= wait["ts"]
+        assert wait["ts"] + wait["dur"] <= step["ts"] + step["dur"] + 1e-6
+    for name in ("loop/batch", "loop/metrics_pull", "loop/heartbeat", "loop/log"):
+        assert sum(r["name"] == name for r in rows) == 3, name
