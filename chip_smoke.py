@@ -73,9 +73,12 @@ SEED = 0
 OPTIMIZERS = ("coap-adamw", "8bit-coap-adamw")
 CKPT_ROOT = os.path.join(ROOT, "artifacts", "chip_smoke")
 
-# Kernel shapes: LLaMA-1B's projected (m, n) at rank 512, and an Eqn-6
-# shape whose VMEM plan builds the fused kernel.
+# Kernel shapes: LLaMA-1B's projected (m, n) at rank 512, its up/gate as
+# stored (n, m) for the int8 kernel, which reads them transposed and with a
+# partial last row block, and an Eqn-6 shape whose VMEM plan builds the
+# fused kernel.
 FUSED_SHAPES = ((5461, 2048, 512), (2048, 2048, 512))
+Q8_STORED_SHAPES = ((2048, 5461, 512),)
 EQN6_SHAPE = (2048, 2048, 128)
 # Tolerances of the interpret-mode tests in tests/test_kernels.py.
 FUSED_TOL = dict(rtol=3e-5, atol=3e-5)
@@ -178,8 +181,22 @@ def _max_violation(got, want, rtol, atol):
     return float(np.max(np.abs(got - want) - (atol + rtol * np.abs(want))))
 
 
+def _q8_row(name, got, want, text):
+    """A parity row of the int8 kernel: codes within one step, scales and
+    ΔW within ``FUSED_TOL``."""
+    code_diff = max(
+        int(np.max(np.abs(np.asarray(a, np.int32) - np.asarray(b, np.int32))))
+        for a, b in ((got[0], want[0]), (got[2], want[2]))
+    )
+    worst = max(_max_violation(got[i], want[i], **FUSED_TOL)
+                for i in (1, 3, 4))
+    return dict(name=name, worst=worst, code_diff=code_diff,
+                ok=worst <= 0 and code_diff <= 1,
+                custom_call="tpu_custom_call" in text)
+
+
 def kernel_parity(fused_shapes=FUSED_SHAPES, eqn6_shape=EQN6_SHAPE,
-                  seed=SEED):
+                  q8_stored_shapes=Q8_STORED_SHAPES, seed=SEED):
     """Each main-path kernel, through ``kernels/ops``, against its oracle.
 
     Returns one row per check: ``{name, ok, custom_call, ...}``. The oracle
@@ -208,17 +225,22 @@ def kernel_parity(fused_shapes=FUSED_SHAPES, eqn6_shape=EQN6_SHAPE,
                               g8, p, mq, ms, vq, vs, count)
         want = jax.jit(ref.coap_fused_update_q8)(
             g8, p, mq, ms, vq, vs, count)
-        code_diff = max(
-            int(np.max(np.abs(np.asarray(a, np.int32)
-                              - np.asarray(b, np.int32))))
-            for a, b in ((got[0], want[0]), (got[2], want[2]))
-        )
-        worst = max(_max_violation(got[i], want[i], **FUSED_TOL)
-                    for i in (1, 3, 4))
-        rows.append(dict(name=f"fused_q8 {m}x{n} r{r}", worst=worst,
-                         code_diff=code_diff,
-                         ok=worst <= 0 and code_diff <= 1,
-                         custom_call="tpu_custom_call" in text))
+        rows.append(_q8_row(f"fused_q8 {m}x{n} r{r}", got, want, text))
+
+    # The int8 kernel on a gradient stored (n, m): it reads G and writes
+    # ΔW that way; the oracle takes G swapped to (m, n).
+    for n, m, r in q8_stored_shapes:
+        g8 = _rand((n, m), seed, 0.1)
+        p = _rand((n, r), seed + 1, 1.0 / math.sqrt(r))
+        mq, ms = ref.quantize_rowblock(_rand((m, r), seed + 2, 0.05))
+        vq, vs = ref.quantize_rowblock(jnp.abs(_rand((m, r), seed + 3, 0.01)))
+        got, text = _compiled(
+            lambda *a: ops.coap_fused_update_q8(*a, transposed=True),
+            g8, p, mq, ms, vq, vs, count)
+        want = jax.jit(ref.coap_fused_update_q8)(
+            g8.T, p, mq, ms, vq, vs, count)
+        got = got[:4] + (got[4].T,)
+        rows.append(_q8_row(f"fused_q8 stored {n}x{m} r{r}", got, want, text))
 
     m, n, r = eqn6_shape
     g = _rand((m, n), seed + 4, 0.1)

@@ -57,6 +57,7 @@ from repro.core import correlation, projector, recalibrate
 from repro.core import stacked_state
 from repro.core.coap_adam import (
     ProjectedAdamConfig,
+    _array_loader,
     _bucket_cfg,
     _maybe_transplant,
     _refresh_p,
@@ -181,7 +182,8 @@ def scale_by_projected_adafactor(cfg: ProjectedAdafactorConfig) -> GradientTrans
             return leaf.m[sl].astype(jnp.float32)
 
         new_p, refreshed = _refresh_p(
-            bcfg, spec, p_old, gc, m_loader, count, idx_arr, phases
+            bcfg, spec, p_old, _array_loader(gc), m_loader, count, idx_arr,
+            phases,
         )
         # Projection-health emit (obs/health): refresh-boundary metrics
         # (captured energy, Eqn-6 residual, subspace overlap) ride the
@@ -190,7 +192,7 @@ def scale_by_projected_adafactor(cfg: ProjectedAdafactorConfig) -> GradientTrans
         # disabled (bit-identical compiled program).
         health.emit_refresh_matrix(
             health.bucket_label("project", g.shape[1:], g.dtype),
-            gc, p_old, new_p, refreshed, count,
+            lambda: gc, p_old, new_p, refreshed, count,
         )
         m = _maybe_transplant(
             bcfg, leaf.m, p_old, new_p, refreshed, phases, count

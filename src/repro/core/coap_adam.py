@@ -503,11 +503,16 @@ def _stagger_dispatch(groups, count, t_u: int, noop, group_fn, full_fn):
     return lax.switch(_stagger_select(groups, count, t_u), branches)
 
 
+def _array_loader(x):
+    """A ``_refresh_p`` loader over an array that is already canonical."""
+    return lambda sl=slice(None): x[sl]
+
+
 def _refresh_p(
     cfg: ProjectedAdamConfig,
     spec: ProjSpec,
     p: jnp.ndarray,
-    gc: jnp.ndarray,
+    g_loader,
     m_loader,
     count: jnp.ndarray,
     idx_arr: jnp.ndarray,
@@ -515,15 +520,15 @@ def _refresh_p(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Strategy-specific P refresh on a stacked leaf bucket.
 
-    ``p``/``gc`` carry a leading (B,) bucket axis; ``idx_arr`` (B,) holds the
+    ``p`` carries a leading (B,) bucket axis; ``idx_arr`` (B,) holds the
     ORIGINAL flat leaf indices (flora folds them into its per-leaf RNG keys,
-    so bucketing never changes the random stream). ``m_loader`` is invoked
-    lazily inside the refresh branch — quantized M is only dequantized on the
-    (rare) refresh steps, never in the per-step hot loop. Staggered group
-    branches pass it a bucket-axis ``slice`` so only the refreshing slice is
-    ever dequantized (per-leaf callers may supply a zero-arg loader: the
-    single-group path calls it without arguments). ``gc`` may be bf16
-    (every refresh primitive upcasts internally).
+    so bucketing never changes the random stream). ``g_loader`` and
+    ``m_loader`` return the canonical gradient and the first moment of a
+    bucket-axis ``slice`` (all of it without one). Both are invoked lazily
+    inside the refresh branch — G is only canonicalized and quantized M only
+    dequantized on the (rare) refresh steps, never in the per-step hot loop,
+    and staggered group branches load only the refreshing slice. The
+    gradient may be bf16 (every refresh primitive upcasts internally).
 
     ``phases`` (len B, non-decreasing) staggers the schedule: leaf b
     refreshes when ``(count + phases[b]) % T_u == 0`` — plus the mandatory
@@ -562,6 +567,7 @@ def _refresh_p(
             do_ref, do_recal = _sched_preds(count, groups[0][2], t_u, cfg.lam)
 
             def refreshed():
+                gc = g_loader()
                 return lax.cond(
                     do_recal,
                     lambda: recalibrate.lowcost_svd(gc, p),
@@ -572,7 +578,7 @@ def _refresh_p(
 
         def refresh_slice(s0, sz, ph):
             p_g = p[s0:s0 + sz]
-            gc_g = gc[s0:s0 + sz]
+            gc_g = g_loader(slice(s0, s0 + sz))
             _, do_recal = _sched_preds(count, ph, t_u, cfg.lam)
             return lax.cond(
                 do_recal,
@@ -581,7 +587,7 @@ def _refresh_p(
             )
 
         new_p = _staggered(
-            refresh_slice, lambda: recalibrate.lowcost_svd(gc, p)
+            refresh_slice, lambda: recalibrate.lowcost_svd(g_loader(), p)
         )
         return new_p, mask
 
@@ -590,24 +596,26 @@ def _refresh_p(
             do_ref, _ = _sched_preds(count, groups[0][2], t_u, cfg.lam)
             new_p = lax.cond(
                 do_ref,
-                lambda: recalibrate.galore_svd(gc, spec.rank).astype(p.dtype),
+                lambda: recalibrate.galore_svd(
+                    g_loader(), spec.rank).astype(p.dtype),
                 lambda: p,
             )
             return new_p, mask
 
         def refresh_slice(s0, sz, ph):
             return recalibrate.galore_svd(
-                gc[s0:s0 + sz], spec.rank
+                g_loader(slice(s0, s0 + sz)), spec.rank
             ).astype(p.dtype)
 
         new_p = _staggered(
             refresh_slice,
-            lambda: recalibrate.galore_svd(gc, spec.rank).astype(p.dtype),
+            lambda: recalibrate.galore_svd(
+                g_loader(), spec.rank).astype(p.dtype),
         )
         return new_p, mask
 
     # flora
-    elem_shape = gc.shape[1:]
+    elem_shape = jax.eval_shape(g_loader).shape[1:]
 
     def resample_idx(idx_slice):
         def one(i):
@@ -750,15 +758,20 @@ def scale_by_projected_adam(cfg: ProjectedAdamConfig) -> GradientTransformation:
         arrays carry a leading (B,) axis; B == 1 for singleton buckets).
         ``cfg`` is the BUCKET-effective config (plan overrides applied —
         shadows the transform's global config on purpose).
-        ``gc`` keeps the gradient's dtype — bf16 gradients stream into the
-        fused kernels as bf16 (upcast per-tile in VMEM, halving per-step G
-        traffic); only the unfused jnp fallbacks materialize fp32."""
-        with jax.named_scope("update"):  # the kernel's layout, m >= n
-            gc = projector.to_canonical(g, spec)
+        The fused kernels read ``g`` as stored, in its dtype — bf16
+        gradients stream in as bf16 (upcast per-tile in VMEM, halving
+        per-step G traffic) — and write ΔW as stored: no copy of G or ΔW
+        is made on a step that refreshes nothing. Only the refresh and the
+        unfused jnp fallbacks see the canonical (m >= n) gradient, through
+        ``g_loader``; the fallbacks materialize fp32."""
         p_old = leaf.p
 
-        # Loader takes an optional bucket-axis slice so staggered group
-        # refreshes only dequantize/upcast the slice they actually update.
+        # Loaders take an optional bucket-axis slice so staggered group
+        # refreshes only canonicalize/dequantize/upcast the slice they
+        # actually update.
+        def g_loader(sl=slice(None)):
+            return projector.to_canonical(g[sl], spec)
+
         if cfg.quantize:
             def m_loader(sl=slice(None)):
                 return kops.dequantize_rowblock(
@@ -772,17 +785,17 @@ def scale_by_projected_adam(cfg: ProjectedAdamConfig) -> GradientTransformation:
         # "refresh" scope, the per-step kernel under "update" (op_name).
         with jax.named_scope("refresh"):
             new_p, refreshed = _refresh_p(
-                cfg, spec, p_old, gc, m_loader, count, idx_arr, phases
+                cfg, spec, p_old, g_loader, m_loader, count, idx_arr, phases
             )
 
-            # Projection-health emit (obs/health): refresh-boundary metrics
-            # computed where G is already materialized, under the same
-            # lax.cond as the refresh — non-refresh steps execute nothing, so
-            # the hot path keeps zero extra G round-trips. Trace-time no-op
-            # (identical compiled program) when no monitor is configured.
+            # Projection-health emit (obs/health): refresh-boundary metrics,
+            # loading G under a lax.cond on the refresh mask — non-refresh
+            # steps execute nothing, so the hot path keeps zero extra G
+            # round-trips. Trace-time no-op (identical compiled program)
+            # when no monitor is configured.
             health.emit_refresh_matrix(
                 health.bucket_label("project", g.shape[1:], g.dtype),
-                gc, p_old, new_p, refreshed, count,
+                g_loader, p_old, new_p, refreshed, count,
             )
 
         if cfg.quantize:
@@ -841,18 +854,20 @@ def scale_by_projected_adam(cfg: ProjectedAdamConfig) -> GradientTransformation:
             with jax.named_scope("update"):
                 if cfg.use_fused_kernel:
                     # Single-pass fused int8 step: no fp32 M/V, no Δ_proj in HBM.
-                    nmq, nms, nvq, nvs, update_c = kops.coap_fused_update_q8(
-                        gc, new_p, m_q, m_s, leaf.v, leaf.v_scale, t,
+                    nmq, nms, nvq, nvs, update = kops.coap_fused_update_q8(
+                        g, new_p, m_q, m_s, leaf.v, leaf.v_scale, t,
                         b1=cfg.b1, b2=cfg.b2, eps=cfg.eps, block=cfg.quant_block,
+                        transposed=spec.transpose,
                     )
                 else:
                     # Unfused 8-bit schedule — every intermediate round-trips
                     # HBM; kept as the benchmark baseline (benchmarks/overhead).
                     # The oracle IS that schedule expressed as jnp ops.
                     nmq, nms, nvq, nvs, update_c = kref.coap_fused_update_q8(
-                        gc, new_p, m_q, m_s, leaf.v, leaf.v_scale, t,
+                        g_loader(), new_p, m_q, m_s, leaf.v, leaf.v_scale, t,
                         b1=cfg.b1, b2=cfg.b2, eps=cfg.eps, block=cfg.quant_block,
                     )
+                    update = projector.from_canonical(update_c, spec)
                 new_leaf = ProjLeaf(p=new_p, m=nmq, v=nvq, m_scale=nms,
                                     v_scale=nvs, ef=leaf.ef)
         else:
@@ -864,18 +879,21 @@ def scale_by_projected_adam(cfg: ProjectedAdamConfig) -> GradientTransformation:
                 )
             with jax.named_scope("update"):
                 if cfg.use_fused_kernel:
-                    new_m, new_v, update_c = kops.coap_fused_update_bp(
-                        gc, new_p, m, v, t, b1=cfg.b1, b2=cfg.b2, eps=cfg.eps
+                    new_m, new_v, update = kops.coap_fused_update_bp(
+                        g, new_p, m, v, t, b1=cfg.b1, b2=cfg.b2, eps=cfg.eps,
+                        transposed=spec.transpose,
                     )
                 else:
-                    g_proj = projector.project(gc.astype(jnp.float32), new_p)
+                    g_proj = projector.project(
+                        g_loader().astype(jnp.float32), new_p)
                     new_m = cfg.b1 * m + (1.0 - cfg.b1) * g_proj
                     new_v = cfg.b2 * v + (1.0 - cfg.b2) * jnp.square(g_proj)
                     tf = t.astype(jnp.float32)
                     delta_proj = (new_m / (1.0 - cfg.b1**tf)) / (
                         jnp.sqrt(new_v / (1.0 - cfg.b2**tf)) + cfg.eps
                     )
-                    update_c = projector.backproject(delta_proj, new_p)
+                    update = projector.from_canonical(
+                        projector.backproject(delta_proj, new_p), spec)
                 new_leaf = ProjLeaf(
                     p=new_p,
                     m=new_m.astype(cfg.state_dtype),
@@ -885,7 +903,7 @@ def scale_by_projected_adam(cfg: ProjectedAdamConfig) -> GradientTransformation:
                     ef=leaf.ef,
                 )
         with jax.named_scope("update"):
-            update = projector.from_canonical(update_c, spec) * cfg.update_scale
+            update = update * cfg.update_scale
         return update.astype(g.dtype), new_leaf
 
     def _update_dense_leaf(cfg, leaf: DenseLeaf, g, count, t):

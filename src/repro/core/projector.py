@@ -4,7 +4,9 @@ Conventions (paper §3.1): for a weight ``W ∈ R^{m×n}`` with ``m ≥ n`` the
 projection is on the right: ``P ∈ R^{n×r}``, ``G_proj = G P ∈ R^{m×r}`` —
 moments live on the *large* side (matches the paper's memory accounting for
 LLaMA-1B, −61% at rank 512). Weights with ``m < n`` are transposed into this
-canonical orientation on entry and transposed back on exit.
+canonical orientation on entry and transposed back on exit — by the jnp
+paths and the refresh; the fused update kernels read such a gradient as
+stored (``transposed``) and copy nothing.
 
 All primitives operate on the **last two axes** and broadcast over leading
 axes. This is how scan-over-layers models (stacked ``(L, m, n)`` weights) and

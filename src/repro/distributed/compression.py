@@ -99,6 +99,7 @@ from repro.core.coap_adam import (
     ProjLeaf,
     ProjectedAdamConfig,
     ProjectedAdamState,
+    _array_loader,
     _bucket_cfg,
     _leaf_cfg,
     _load,
@@ -257,7 +258,7 @@ def _update_proj_compressed(lcfg, leaf: ProjLeaf, g, spec, count, t, idx,
     # original flat idx keeps flora's per-leaf RNG stream unchanged; the
     # single phase (ph,) reproduces this leaf's staggered cadence).
     new_p, refreshed = _refresh_p(
-        lcfg, spec, p_old[None], gc_full[None], m_loader, count,
+        lcfg, spec, p_old[None], _array_loader(gc_full[None]), m_loader, count,
         jnp.asarray([idx], jnp.int32), (ph,),
     )
     new_p = new_p[0]

@@ -18,15 +18,18 @@ Tiling: grid (m/bm, n/bn), n innermost ('arbitrary') for the reduction;
 blocks start at bm=512, bn=512, with all MXU dims 128-aligned. For the
 back-projection kernel ``plan_two_phase_tiles`` shrinks them until the
 estimated scoped VMEM (``bp_vmem_bytes``) fits the 16 MiB limit: at r=512
-with fp32 G that is (512, 128). The wrapper pads ragged shapes and vmaps
-over leading (layer/expert) stack axes.
+with fp32 G that is (512, 128). The back-projection kernel reads G and
+writes ΔW as the parameter stores them (``transposed`` for m < n) and at
+their true row count (a partial last row block), so no copy of either is
+made around it; only the contracted axis n is padded, where it is not a
+multiple of bn. The wrappers vmap over leading (layer/expert) stack axes.
 
 bf16 gradient streaming: G blocks are DMA'd in the caller's dtype and
 upcast to fp32 in VMEM (the ``astype`` inside the body), so bf16 training
 halves the kernel's dominant HBM read (the m·n gradient) with fp32 MXU
 accumulation — the optimizer never materializes an fp32 copy of G
-(``coap_adam._update_proj_bucket`` passes the canonical gradient through
-uncast; only the unfused jnp fallbacks cast eagerly).
+(``coap_adam._update_proj_bucket`` passes the gradient through as
+stored and uncast; only the unfused jnp fallbacks cast eagerly).
 
 ``coap_fused_update_bp_pallas`` additionally fuses the back-projection
 ``ΔW = Δ_proj Pᵀ`` as a second MXU stage in the SAME kernel: the inner grid
@@ -90,10 +93,12 @@ def _kernel(corr_ref, g_ref, p_ref, m_ref, v_ref,
 
 def _kernel_bp(corr_ref, g_ref, p_ref, m_ref, v_ref,
                new_m_ref, new_v_ref, dw_ref, acc_ref,
-               *, b1: float, b2: float, eps: float, kn: int):
+               *, b1: float, b2: float, eps: float, kn: int,
+               transposed: bool):
     """Two-phase body: phase 1 accumulates G@P; the k==kn-1 epilogue runs the
     Adam update and parks Δ_proj in the accumulator scratch; phase 2 emits
-    the back-projected (bm, bn) tiles of ΔW = Δ_proj Pᵀ."""
+    the back-projected tiles of ΔW = Δ_proj Pᵀ, in G's stored orientation
+    (``backproject_tile``)."""
     k = pl.program_id(1)
 
     @pl.when(k == 0)
@@ -102,12 +107,7 @@ def _kernel_bp(corr_ref, g_ref, p_ref, m_ref, v_ref,
 
     @pl.when(k < kn)
     def _accumulate():
-        acc_ref[...] += jnp.dot(
-            g_ref[...].astype(jnp.float32),
-            p_ref[...].astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-            precision=MXU_PRECISION,
-        )
+        acc_ref[...] += project_tile(g_ref[...], p_ref[...], transposed)
 
     @pl.when(k == kn - 1)
     def _epilogue():
@@ -125,13 +125,7 @@ def _kernel_bp(corr_ref, g_ref, p_ref, m_ref, v_ref,
 
     @pl.when(k >= kn)
     def _backproject():
-        # (bm, r) @ (bn, r)ᵀ on the MXU, contracting r.
-        dw_ref[...] = jax.lax.dot_general(
-            acc_ref[...], p_ref[...].astype(jnp.float32),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=MXU_PRECISION,
-        )
+        dw_ref[...] = backproject_tile(acc_ref[...], p_ref[...], transposed)
 
 
 def _pad_to(x, mult, axis):
@@ -144,17 +138,77 @@ def _pad_to(x, mult, axis):
 
 
 # Shared two-phase grid pieces (also used by quant8's fused int8 kernel so
-# the two fused variants stay in lockstep):
-def pin_g_index(kn):
+# the two fused variants stay in lockstep). Both kernels read G, and write
+# ΔW, in the orientation the parameter is stored in: canonical (m, n) with
+# m >= n, or ``transposed`` (n, m), tiled (bn, bm). The row axis m may end
+# in a partial block: Pallas reads its out-of-range rows as garbage and
+# masks their writes, and every row's moments and ΔW depend on that row
+# alone. The contracted axis n is padded to whole blocks, since garbage
+# there would enter every row's sum.
+def fused_dims(shape, transposed):
+    """(m, n) of a gradient of ``shape`` stored canonical or ``transposed``
+    in its last two axes."""
+    rows, cols = shape[-2:]
+    return (cols, rows) if transposed else (rows, cols)
+
+
+def g_block(bm, bn, transposed):
+    return (bn, bm) if transposed else (bm, bn)
+
+
+def pin_g_index(kn, transposed=False):
     """G streams through phase 1, then stays pinned on its last block
     (index unchanged -> no phase-2 refetch)."""
-    return lambda i, k: (i, jnp.where(k < kn, k, kn - 1))
+    def index(i, k):
+        kk = jnp.where(k < kn, k, kn - 1)
+        return (kk, i) if transposed else (i, kk)
+    return index
 
 
-def park_out_index(kn):
+def park_out_index(kn, transposed=False):
     """ΔW tiles park on block 0 through phase 1 (no copy-out until the
     index advances), then advance one tile per phase-2 step."""
-    return lambda i, k: (i, jnp.maximum(k - kn, 0))
+    def index(i, k):
+        kk = jnp.maximum(k - kn, 0)
+        return (kk, i) if transposed else (i, kk)
+    return index
+
+
+def project_tile(g, p, transposed):
+    """Phase 1: a G tile times its (bn, r) P tile -> (bm, r), contracting
+    n, which is the G tile's first axis when ``transposed``."""
+    contract = (0,) if transposed else (1,)
+    return jax.lax.dot_general(
+        g.astype(jnp.float32), p.astype(jnp.float32),
+        dimension_numbers=((contract, (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=MXU_PRECISION,
+    )
+
+
+def backproject_tile(delta, p, transposed):
+    """Phase 2: the (bm, bn) tile Δ Pᵀ, or the (bn, bm) tile P Δᵀ when
+    ``transposed``; both contract r on the MXU."""
+    p = p.astype(jnp.float32)
+    lhs, rhs = (p, delta) if transposed else (delta, p)
+    return jax.lax.dot_general(
+        lhs, rhs,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=MXU_PRECISION,
+    )
+
+
+def pad_contracted(g, p, bn, transposed):
+    """G and P padded along n to whole ``bn`` blocks (zeros add nothing)."""
+    return _pad_to(g, bn, 0 if transposed else 1), _pad_to(p, bn, 0)
+
+
+def trim_contracted(dw, n, transposed):
+    """ΔW without the padded n of ``pad_contracted``."""
+    if (dw.shape[0] if transposed else dw.shape[1]) == n:
+        return dw
+    return dw[:n] if transposed else dw[:, :n]
 
 
 # Mosaic's default scoped-VMEM limit for one kernel on TPU v5e, less 2 MiB
@@ -276,24 +330,27 @@ def coap_fused_update_pallas(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("b1", "b2", "eps", "interpret", "bm", "bn")
+    jax.jit,
+    static_argnames=("b1", "b2", "eps", "interpret", "bm", "bn", "transposed"),
 )
 def coap_fused_update_bp_pallas(
     g, p, m, v, count, b1=0.9, b2=0.999, eps=1e-8,
     interpret: bool = False, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
+    transposed: bool = False,
 ):
     """Back-projection-fused variant: g (...,m,n), p (...,n,r), m/v (...,m,r)
-    -> (m', v', ΔW (...,m,n)). Δ_proj stays in VMEM scratch."""
+    -> (m', v', ΔW (...,m,n)). Δ_proj stays in VMEM scratch. With
+    ``transposed`` G is stored (...,n,m) and ΔW comes out (...,n,m)."""
     if g.ndim > 2:  # stacked weights: vmap over the leading axes
         fn = functools.partial(
             coap_fused_update_bp_pallas, b1=b1, b2=b2, eps=eps,
-            interpret=interpret, bm=bm, bn=bn,
+            interpret=interpret, bm=bm, bn=bn, transposed=transposed,
         )
         for _ in range(g.ndim - 2):
             fn = jax.vmap(fn, in_axes=(0, 0, 0, 0, None))
         return fn(g, p, m, v, count)
 
-    m_dim, n_dim = g.shape
+    m_dim, n_dim = fused_dims(g.shape, transposed)
     r = p.shape[-1]
     t = count.astype(jnp.float32)
     corr = jnp.stack([1.0 - b1**t, 1.0 - b2**t])
@@ -302,23 +359,21 @@ def coap_fused_update_bp_pallas(
     bm_eff, bn_eff = plan_two_phase_tiles(
         m_dim, n_dim, bm, bn, lambda a, b: bp_vmem_bytes(a, b, r, gi)
     )
-    g_p = _pad_to(_pad_to(g, bm_eff, 0), bn_eff, 1)
-    p_p = _pad_to(p, bn_eff, 0)
-    m_p = _pad_to(m.astype(jnp.float32), bm_eff, 0)
-    v_p = _pad_to(v.astype(jnp.float32), bm_eff, 0)
-    mp, np_ = g_p.shape
-    kn = np_ // bn_eff
-    grid = (mp // bm_eff, 2 * kn)
+    g_p, p_p = pad_contracted(g, p, bn_eff, transposed)
+    kn = p_p.shape[0] // bn_eff
+    grid = (pl.cdiv(m_dim, bm_eff), 2 * kn)
 
-    kernel = functools.partial(_kernel_bp, b1=b1, b2=b2, eps=eps, kn=kn)
+    kernel = functools.partial(_kernel_bp, b1=b1, b2=b2, eps=eps, kn=kn,
+                               transposed=transposed)
     out_shape = [
-        jax.ShapeDtypeStruct((mp, r), jnp.float32),
-        jax.ShapeDtypeStruct((mp, r), jnp.float32),
-        jax.ShapeDtypeStruct((mp, np_), jnp.float32),
+        jax.ShapeDtypeStruct((m_dim, r), jnp.float32),
+        jax.ShapeDtypeStruct((m_dim, r), jnp.float32),
+        jax.ShapeDtypeStruct(g_p.shape, jnp.float32),
     ]
+    blk = g_block(bm_eff, bn_eff, transposed)
     in_specs = [
         pl.BlockSpec((2,), lambda i, k: (0,)),  # corr coefficients
-        pl.BlockSpec((bm_eff, bn_eff), pin_g_index(kn)),  # G
+        pl.BlockSpec(blk, pin_g_index(kn, transposed)),  # G
         pl.BlockSpec((bn_eff, r), lambda i, k: (k % kn, 0)),  # P (both phases)
         pl.BlockSpec((bm_eff, r), lambda i, k: (i, 0)),  # M
         pl.BlockSpec((bm_eff, r), lambda i, k: (i, 0)),  # V
@@ -326,7 +381,7 @@ def coap_fused_update_bp_pallas(
     out_specs = [
         pl.BlockSpec((bm_eff, r), lambda i, k: (i, 0)),
         pl.BlockSpec((bm_eff, r), lambda i, k: (i, 0)),
-        pl.BlockSpec((bm_eff, bn_eff), park_out_index(kn)),  # ΔW
+        pl.BlockSpec(blk, park_out_index(kn, transposed)),  # ΔW
     ]
     kwargs = dict(
         grid=grid,
@@ -341,6 +396,6 @@ def coap_fused_update_bp_pallas(
 
     new_m, new_v, dw = pl.pallas_call(
         kernel, name="coap_fused_update_bp_pallas", **kwargs)(
-        corr, g_p, p_p, m_p, v_p
+        corr, g_p, p_p, m.astype(jnp.float32), v.astype(jnp.float32)
     )
-    return new_m[:m_dim], new_v[:m_dim], dw[:m_dim, :n_dim]
+    return new_m, new_v, trim_contracted(dw, n_dim, transposed)

@@ -61,37 +61,73 @@ def coap_fused_update(g, p, m, v, count, b1=0.9, b2=0.999, eps=1e-8):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("b1", "b2", "eps"))
-def coap_fused_update_bp(g, p, m, v, count, b1=0.9, b2=0.999, eps=1e-8):
+def _swap(x):
+    return jnp.swapaxes(x, -1, -2)
+
+
+@functools.partial(jax.jit, static_argnames=("b1", "b2", "eps", "transposed"))
+def coap_fused_update_bp(g, p, m, v, count, b1=0.9, b2=0.999, eps=1e-8,
+                         transposed=False):
     """Back-projection-fused step: returns (m', v', ΔW) with ΔW = Δ_proj Pᵀ
     produced as a second MXU stage of the same kernel — Δ_proj never hits
-    HBM. See ``coap_update.coap_fused_update_bp_pallas``."""
+    HBM. With ``transposed``, g is stored (..., n, m) and ΔW is returned
+    that way. See ``coap_update.coap_fused_update_bp_pallas``."""
     if _mode("coap_fused_update_bp") == "ref":
-        return ref.coap_fused_update_bp(g, p, m, v, count, b1=b1, b2=b2, eps=eps)
+        if not transposed:
+            return ref.coap_fused_update_bp(g, p, m, v, count, b1=b1, b2=b2,
+                                            eps=eps)
+        new_m, new_v, dw = ref.coap_fused_update_bp(
+            _swap(g), p, m, v, count, b1=b1, b2=b2, eps=eps)
+        return new_m, new_v, _swap(dw)
     from repro.kernels import coap_update
 
     return coap_update.coap_fused_update_bp_pallas(
-        g, p, m, v, count, b1=b1, b2=b2, eps=eps, interpret=_interpret_flag()
+        g, p, m, v, count, b1=b1, b2=b2, eps=eps, interpret=_interpret_flag(),
+        transposed=transposed,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("b1", "b2", "eps", "block"))
+def _count_q8_layout(g, p, block, transposed):
+    """One count per trace of the fused int8 kernel under
+    ``kernels/q8_layout/stored_orientation`` when it reads G transposed as
+    stored, and under ``kernels/q8_layout/ragged_rows`` when its last row
+    block is partial."""
+    from repro.kernels import quant8
+    from repro.kernels.coap_update import fused_dims
+    from repro.obs.registry import get_registry
+
+    m_dim, n_dim = fused_dims(g.shape, transposed)
+    r = p.shape[-1]
+    bm, _ = quant8.q8_tiles(m_dim, n_dim, r, ref.rowblock_nblocks(r, block),
+                            jnp.dtype(g.dtype).itemsize)
+    if transposed:
+        get_registry().inc("kernels/q8_layout/stored_orientation")
+    if m_dim % bm:
+        get_registry().inc("kernels/q8_layout/ragged_rows")
+
+
+@functools.partial(jax.jit, static_argnames=("b1", "b2", "eps", "block",
+                                             "transposed"))
 def coap_fused_update_q8(
     g, p, m_q, m_scale, v_q, v_scale, count,
-    b1=0.9, b2=0.999, eps=1e-8, block=ref.QUANT_BLOCK,
+    b1=0.9, b2=0.999, eps=1e-8, block=ref.QUANT_BLOCK, transposed=False,
 ):
     """Single-pass 8-bit COAP step (project + dequant + Adam + requant +
-    back-project in one kernel; row-block codec). See ``quant8``."""
+    back-project in one kernel; row-block codec). With ``transposed``, g is
+    stored (..., n, m) and ΔW is returned that way. See ``quant8``."""
     if _mode("coap_fused_update_q8") == "ref":
-        return ref.coap_fused_update_q8(
-            g, p, m_q, m_scale, v_q, v_scale, count,
-            b1=b1, b2=b2, eps=eps, block=block,
+        out = ref.coap_fused_update_q8(
+            _swap(g) if transposed else g, p, m_q, m_scale, v_q, v_scale,
+            count, b1=b1, b2=b2, eps=eps, block=block,
         )
+        return out[:4] + (_swap(out[4]) if transposed else out[4],)
     from repro.kernels import quant8
 
+    _count_q8_layout(g, p, block, transposed)
     return quant8.coap_fused_update_q8_pallas(
         g, p, m_q, m_scale, v_q, v_scale, count,
         b1=b1, b2=b2, eps=eps, block=block, interpret=_interpret_flag(),
+        transposed=transposed,
     )
 
 

@@ -37,8 +37,11 @@ Hardware adaptation note (DESIGN.md §3): Dettmers' dynamic-tree codebook is
 a CUDA-LUT trick; linear absmax maps onto the TPU VPU (mul + round + clip)
 with no gather. Same state size, slightly coarser tails. TPU tiling note:
 int8 tiles are (32, 128); the fused kernel's row tiles (bm, r) satisfy this
-for bm ≥ 32 and r a lane multiple — the wrapper pads rows, and ragged r is
-exercised under interpret mode (tests) where tiling is unconstrained.
+for bm ≥ 32 and r a lane multiple, and ragged r is exercised under
+interpret mode (tests) where tiling is unconstrained. Like the fp32 fused
+kernel it reads G and writes ΔW as stored, transposed or not, and ends in a
+partial row block where m is not a multiple of bm: rows are independent,
+so the out-of-range rows Pallas reads there reach no output it writes.
 """
 from __future__ import annotations
 
@@ -113,11 +116,15 @@ def _row_pad(x, rows):
 # shared two-phase grid pieces (same tiling semantics as the fp32 fused
 # kernel — see coap_update.py)
 from repro.kernels.coap_update import (  # noqa: E402
-    MXU_PRECISION,
-    _pad_to as _pad_to_axis,
+    backproject_tile,
+    fused_dims,
+    g_block,
+    pad_contracted,
     park_out_index,
     pin_g_index,
     plan_two_phase_tiles,
+    project_tile,
+    trim_contracted,
     two_phase_compiler_params,
 )
 
@@ -254,9 +261,17 @@ def q8_vmem_bytes(bm, bn, r, nblk, g_itemsize):
     return 2 * (inputs + outputs) + tile + 6 * tile
 
 
+def q8_tiles(m, n, r, nblk, g_itemsize, bm=DEFAULT_BM, bn=DEFAULT_BN):
+    """(bm, bn) of the fused int8 kernel for canonical (m, n) at rank r,
+    shrunk until ``q8_vmem_bytes`` fits."""
+    return plan_two_phase_tiles(
+        m, n, bm, bn, lambda a, b: q8_vmem_bytes(a, b, r, nblk, g_itemsize)
+    )
+
+
 def _fused8_proj_kernel(corr_ref, g_ref, p_ref, mq_ref, ms_ref, vq_ref, vs_ref,
                         nmq_ref, nms_ref, nvq_ref, nvs_ref, dw_ref, acc_ref,
-                        *, b1, b2, eps, kn, block):
+                        *, b1, b2, eps, kn, block, transposed):
     k = pl.program_id(1)
 
     @pl.when(k == 0)
@@ -265,12 +280,7 @@ def _fused8_proj_kernel(corr_ref, g_ref, p_ref, mq_ref, ms_ref, vq_ref, vs_ref,
 
     @pl.when(k < kn)
     def _accumulate():
-        acc_ref[...] += jnp.dot(
-            g_ref[...].astype(jnp.float32),
-            p_ref[...].astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-            precision=MXU_PRECISION,
-        )
+        acc_ref[...] += project_tile(g_ref[...], p_ref[...], transposed)
 
     @pl.when(k == kn - 1)
     def _epilogue():
@@ -287,81 +297,74 @@ def _fused8_proj_kernel(corr_ref, g_ref, p_ref, mq_ref, ms_ref, vq_ref, vs_ref,
 
     @pl.when(k >= kn)
     def _backproject():
-        dw_ref[...] = jax.lax.dot_general(
-            acc_ref[...], p_ref[...].astype(jnp.float32),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=MXU_PRECISION,
-        )
+        dw_ref[...] = backproject_tile(acc_ref[...], p_ref[...], transposed)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("b1", "b2", "eps", "block", "interpret", "bm", "bn"),
+    static_argnames=("b1", "b2", "eps", "block", "interpret", "bm", "bn",
+                     "transposed"),
 )
 def coap_fused_update_q8_pallas(
     g, p, m_q, m_scale, v_q, v_scale, count,
     b1=0.9, b2=0.999, eps=1e-8, block=QUANT_BLOCK,
     interpret: bool = False, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
+    transposed: bool = False,
 ):
     """One-kernel 8-bit COAP step. g (...,m,n), p (...,n,r), int8 moments
     (...,m,r) with (...,m,nblk) scales -> (m_q', m_s', v_q', v_s', ΔW).
+    With ``transposed`` G is stored (...,n,m) and ΔW comes out (...,n,m):
+    the kernel tiles the stored array and no copy of G or ΔW is made.
     Broadcasts over leading (layer/expert) stack axes via vmap."""
     if g.ndim > 2:
         fn = functools.partial(
             coap_fused_update_q8_pallas, b1=b1, b2=b2, eps=eps, block=block,
-            interpret=interpret, bm=bm, bn=bn,
+            interpret=interpret, bm=bm, bn=bn, transposed=transposed,
         )
         for _ in range(g.ndim - 2):
             fn = jax.vmap(fn, in_axes=(0, 0, 0, 0, 0, 0, None))
         return fn(g, p, m_q, m_scale, v_q, v_scale, count)
 
-    m_dim, n_dim = g.shape
+    m_dim, n_dim = fused_dims(g.shape, transposed)
     r = p.shape[-1]
     nblk = rowblock_nblocks(r, block)
     assert m_scale.shape[-1] == nblk, (m_scale.shape, nblk)
     t = count.astype(jnp.float32)
     corr = jnp.stack([1.0 - b1**t, 1.0 - b2**t])
 
-    gi = jnp.dtype(g.dtype).itemsize
-    bm_eff, bn_eff = plan_two_phase_tiles(
-        m_dim, n_dim, bm, bn, lambda a, b: q8_vmem_bytes(a, b, r, nblk, gi)
-    )
-    g_p = _pad_to_axis(_pad_to_axis(g, bm_eff, 0), bn_eff, 1)
-    p_p = _pad_to_axis(p, bn_eff, 0)
-    mq_p = _pad_to_axis(m_q, bm_eff, 0)
-    vq_p = _pad_to_axis(v_q, bm_eff, 0)
-    ms_p = _pad_to_axis(m_scale, bm_eff, 0)
-    vs_p = _pad_to_axis(v_scale, bm_eff, 0)
-    mp, np_ = g_p.shape
-    kn = np_ // bn_eff
-    grid = (mp // bm_eff, 2 * kn)
+    bm_eff, bn_eff = q8_tiles(m_dim, n_dim, r, nblk,
+                              jnp.dtype(g.dtype).itemsize, bm, bn)
+    g_p, p_p = pad_contracted(g, p, bn_eff, transposed)
+    kn = p_p.shape[0] // bn_eff
+    grid = (pl.cdiv(m_dim, bm_eff), 2 * kn)
 
     kernel = functools.partial(
-        _fused8_proj_kernel, b1=b1, b2=b2, eps=eps, kn=kn, block=block
+        _fused8_proj_kernel, b1=b1, b2=b2, eps=eps, kn=kn, block=block,
+        transposed=transposed,
     )
     row_q = pl.BlockSpec((bm_eff, r), lambda i, k: (i, 0))
     row_s = pl.BlockSpec((bm_eff, nblk), lambda i, k: (i, 0))
+    blk = g_block(bm_eff, bn_eff, transposed)
     in_specs = [
         pl.BlockSpec((2,), lambda i, k: (0,)),  # corr coefficients
-        pl.BlockSpec((bm_eff, bn_eff), pin_g_index(kn)),  # G
+        pl.BlockSpec(blk, pin_g_index(kn, transposed)),  # G
         pl.BlockSpec((bn_eff, r), lambda i, k: (k % kn, 0)),  # P (both phases)
         row_q, row_s, row_q, row_s,  # int8 M/V + scales
     ]
     out_specs = [
         row_q, row_s, row_q, row_s,
-        pl.BlockSpec((bm_eff, bn_eff), park_out_index(kn)),  # ΔW (phase 2)
+        pl.BlockSpec(blk, park_out_index(kn, transposed)),  # ΔW (phase 2)
     ]
     kwargs = dict(
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=[
-            jax.ShapeDtypeStruct((mp, r), jnp.int8),
-            jax.ShapeDtypeStruct((mp, nblk), jnp.float32),
-            jax.ShapeDtypeStruct((mp, r), jnp.int8),
-            jax.ShapeDtypeStruct((mp, nblk), jnp.float32),
-            jax.ShapeDtypeStruct((mp, np_), jnp.float32),
+            jax.ShapeDtypeStruct((m_dim, r), jnp.int8),
+            jax.ShapeDtypeStruct((m_dim, nblk), jnp.float32),
+            jax.ShapeDtypeStruct((m_dim, r), jnp.int8),
+            jax.ShapeDtypeStruct((m_dim, nblk), jnp.float32),
+            jax.ShapeDtypeStruct(g_p.shape, jnp.float32),
         ],
         interpret=interpret,
     )
@@ -371,12 +374,6 @@ def coap_fused_update_q8_pallas(
 
     nmq, nms, nvq, nvs, dw = pl.pallas_call(
         kernel, name="coap_fused_update_q8_pallas", **kwargs)(
-        corr, g_p, p_p, mq_p, ms_p, vq_p, vs_p
+        corr, g_p, p_p, m_q, m_scale, v_q, v_scale
     )
-    return (
-        nmq[:m_dim],
-        nms[:m_dim],
-        nvq[:m_dim],
-        nvs[:m_dim],
-        dw[:m_dim, :n_dim],
-    )
+    return nmq, nms, nvq, nvs, trim_contracted(dw, n_dim, transposed)

@@ -214,12 +214,14 @@ def read_health(path: str) -> List[Dict[str, Any]]:
 # ---------------------------------------------------------------------------
 # Device-side refresh emitters (called INSIDE the optimizer's jitted update)
 # ---------------------------------------------------------------------------
-def emit_refresh_matrix(label: str, gc, p_old, p_new, refreshed, count):
+def emit_refresh_matrix(label: str, g_loader, p_old, p_new, refreshed,
+                        count):
     """Refresh-boundary metrics for a stacked matrix bucket, from inside
-    the jitted update. ``gc`` (B,m,n) is the canonical gradient the
-    refresh just consumed, ``p_old``/``p_new`` (B,n,r), ``refreshed`` the
-    (B,) bool mask. Everything runs under ``lax.cond(any(refreshed))`` so
-    non-refresh steps execute nothing — zero extra G traffic — and ships
+    the jitted update. ``g_loader()`` gives the (B,m,n) canonical gradient
+    the refresh just consumed, ``p_old``/``p_new`` (B,n,r), ``refreshed``
+    the (B,) bool mask. Everything, the load of G included, runs under
+    ``lax.cond(any(refreshed))`` so non-refresh steps execute nothing —
+    zero extra G traffic — and ships
     through ``jax.debug.callback`` (no value flows back: numerics are
     untouched). No-op (checked at trace time) when the monitor is
     disabled, so untraced runs compile bit-identical programs."""
@@ -239,7 +241,7 @@ def emit_refresh_matrix(label: str, gc, p_old, p_new, refreshed, count):
         })
 
     def do():
-        g32 = gc.astype(jnp.float32)
+        g32 = g_loader().astype(jnp.float32)
         pn = p_new.astype(jnp.float32)
         po = p_old.astype(jnp.float32)
         mask = refreshed.astype(jnp.float32)

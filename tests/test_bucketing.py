@@ -223,3 +223,148 @@ def test_mixed_tree_full_optimizer_runs():
         upd, state = step(g, state)
     for leaf in jax.tree_util.tree_leaves(upd):
         assert bool(jnp.all(jnp.isfinite(leaf)))
+
+
+# ---------------------------------------------------------------------------
+# The fused kernels read G as stored: no layout copies around them
+# ---------------------------------------------------------------------------
+def _layout_params():
+    """One leaf stored transposed (m < n; 512 rows, whole 512-row blocks)
+    and one stored canonical with 520 rows (a partial last row block)."""
+    return {"t": {"w": jnp.zeros((128, 512))},
+            "r": {"w": jnp.zeros((520, 128))},
+            "bias": jnp.zeros((7,))}
+
+
+def _layout_cfg(**kw):
+    return _cfg(rules=ProjectionRules(rank=64, min_dim=8), **kw)
+
+
+def _pad_rows(x, rows):
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 2)
+                   + [(0, rows - x.shape[-2]), (0, 0)])
+
+
+def _canonical_call(kernel):
+    """The fused kernel as called before it read G as stored: G swapped to
+    m >= n, rows zero-padded to whole 512-row blocks, and ΔW cut back and
+    swapped to the stored orientation."""
+    def call(g, p, *args, transposed=False, **kw):
+        *states, count = args
+        gc = jnp.swapaxes(g, -1, -2) if transposed else g
+        m_dim = gc.shape[-2]
+        rows = -(-m_dim // 512) * 512
+        out = kernel(_pad_rows(gc, rows), p,
+                     *(_pad_rows(x, rows) for x in states), count, **kw)
+        out = tuple(x[..., :m_dim, :] for x in out)
+        dw = jnp.swapaxes(out[-1], -1, -2) if transposed else out[-1]
+        return out[:-1] + (dw,)
+    return call
+
+
+@pytest.mark.parametrize("quantize", [True, False], ids=["q8", "fp32"])
+def test_stored_layout_matches_canonical_path(quantize, monkeypatch):
+    """A transposed and a ragged leaf through the fused kernels, as stored,
+    give the bits of the canonical, padded call, updates and states, over
+    steps that refresh (Eqn 7 at the first, Eqn 6 and Eqn 7 after)."""
+    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+    params = _layout_params()
+    g = _grads(params, seed=5)
+    name = "coap_fused_update_q8" if quantize else "coap_fused_update_bp"
+    outs = []
+    for patch in (False, True):
+        if patch:
+            monkeypatch.setattr(kops, name,
+                                _canonical_call(getattr(kops, name)))
+        tx = scale_by_projected_adam(
+            _layout_cfg(quantize=quantize, t_update=2, lam=2))
+        state = tx.init(params)
+        step = jax.jit(lambda gg, s: tx.update(gg, s, None))
+        for _ in range(5):
+            upd, state = step(g, state)
+        outs.append((upd, state))
+    for a, b in zip(jax.tree_util.tree_leaves(outs[0]),
+                    jax.tree_util.tree_leaves(outs[1])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _layout_copies(jaxpr, min_size):
+    """(primitive, operand shape) of each transpose, pad or slice that
+    moves or cuts the last two axes of an operand of ``min_size`` elements
+    or more, outside conditional branches (the refresh) and kernels."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("cond", "pallas_call"):
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, Jaxpr):
+                    found += _layout_copies(sub, min_size)
+        if not eqn.invars or not hasattr(eqn.invars[0].aval, "shape"):
+            continue
+        shape = eqn.invars[0].aval.shape
+        if len(shape) < 2 or np.prod(shape) < min_size:
+            continue
+        nd = len(shape)
+        p = eqn.params
+        moved = (
+            (name == "transpose"
+             and tuple(p["permutation"][-2:]) != (nd - 2, nd - 1))
+            or (name == "pad" and any(any(c) for c in p["padding_config"][-2:]))
+            or (name == "slice"
+                and (tuple(p["start_indices"][-2:]) != (0, 0)
+                     or tuple(p["limit_indices"][-2:]) != shape[-2:]))
+        )
+        if moved:
+            found.append((name, shape))
+    return found
+
+
+@pytest.mark.parametrize("quantize", [True, False], ids=["q8", "fp32"])
+def test_step_copies_no_gradient_outside_refresh(quantize, monkeypatch):
+    """Outside the refresh's conditional branches the traced update holds
+    no transpose, pad or slice of a gradient or update of a projected
+    leaf: the fused kernels read G and write ΔW as stored."""
+    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+    params = _layout_params()
+    tx = scale_by_projected_adam(_layout_cfg(quantize=quantize))
+    state = tx.init(params)
+    closed = jax.make_jaxpr(lambda gg, s: tx.update(gg, s, None))(
+        _grads(params), state)
+    assert _layout_copies(closed.jaxpr, 128 * 512) == []
+    # the check sees the copies the refresh makes inside its branches
+    found = []
+    for eqn in closed.jaxpr.eqns:
+        for sub in eqn.params.get("branches", ()):
+            found += _layout_copies(sub.jaxpr, 128 * 512)
+    assert ("transpose", (1, 128, 512)) in found
+
+
+def test_q8_layout_counters(monkeypatch):
+    """Per trace of the update: one fused int8 call read G transposed as
+    stored, one ended in a partial row block."""
+    from repro.obs.registry import get_registry
+
+    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+    # shapes of their own, so that no earlier trace is reused
+    params = {"t": {"w": jnp.zeros((96, 1024))},   # transposed, 1024 rows
+              "r": {"w": jnp.zeros((1100, 96))},   # canonical, ragged
+              "s": {"w": jnp.zeros((768, 96))}}    # canonical, ragged
+    tx = scale_by_projected_adam(_layout_cfg(quantize=True))
+    state = tx.init(params)
+
+    def counts():
+        c = get_registry().snapshot()["counters"]
+        return [c.get(f"kernels/q8_layout/{k}", 0)
+                for k in ("stored_orientation", "ragged_rows")]
+
+    before = counts()
+    jax.make_jaxpr(lambda gg, s: tx.update(gg, s, None))(
+        _grads(params), state)
+    after = counts()
+    assert [a - b for a, b in zip(after, before)] == [1, 2]

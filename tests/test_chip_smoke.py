@@ -28,8 +28,9 @@ def test_kernel_parity_phase_interpret(monkeypatch):
     rows = chip_smoke.kernel_parity(
         fused_shapes=((136, 384, 128), (264, 256, 128)),
         eqn6_shape=(200, 384, 128),
+        q8_stored_shapes=((256, 392, 128),),
     )
-    assert [r["ok"] for r in rows] == [True] * 5
+    assert [r["ok"] for r in rows] == [True] * 6
     # interpret mode lowers the bodies to plain XLA: no TPU kernel
     assert not any(r["custom_call"] for r in rows)
 

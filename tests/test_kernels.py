@@ -287,6 +287,64 @@ def test_coap_fused_update_q8_stacked_leaves():
         )
 
 
+def _fused_args(kernel, lead, m_dim, n_dim, r=64):
+    """P and the moments of a fused call (int8 codes and scales for q8),
+    from fixed seeds."""
+    p = _rand(lead + (n_dim, r), 1) / np.sqrt(r)
+    m0 = 0.05 * _rand(lead + (m_dim, r), 2)
+    v0 = jnp.abs(0.01 * _rand(lead + (m_dim, r), 3))
+    if kernel == "q8":
+        return p, ref.quantize_rowblock(m0) + ref.quantize_rowblock(v0)
+    return p, (m0, v0)
+
+
+def _fused(kernel, g, p, states, transposed=False):
+    fn = {"q8": coap_fused_update_q8_pallas,
+          "bp": coap_fused_update_bp_pallas}[kernel]
+    return fn(g, p, *states, jnp.asarray(7, jnp.int32), interpret=True,
+              bm=128, bn=128, transposed=transposed)
+
+
+def _pad_rows(x, rows):
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 2)
+                   + [(0, rows - x.shape[-2]), (0, 0)])
+
+
+@pytest.mark.parametrize("lead", [(), (3,)], ids=["2d", "vmap"])
+@pytest.mark.parametrize("kernel", ["q8", "bp"])
+@pytest.mark.parametrize("layout", ["stored_orientation", "ragged_rows"])
+def test_fused_layout_bit_exact(layout, kernel, lead):
+    """The fused kernels read G as stored and at its true row count.
+
+    ``stored_orientation``: on a transposed (n, m) gradient the kernel
+    gives the bits of the canonical call on its swapaxes — int8 codes,
+    scales, moments, and ΔW once transposed back — with m = 300 ending in a
+    partial row block of 128 and n = 320 padded to whole column blocks.
+    ``ragged_rows``: the call with a partial last row block gives the bits
+    of the call on G and moments zero-padded to whole row blocks.
+
+    At rank 64 the CPU backend sums each dot in the same order for either
+    operand layout; below it, the stored and canonical products can differ
+    in their last bits on the CPU."""
+    m_dim, n_dim = 300, 320
+    g = 0.1 * _rand(lead + (m_dim, n_dim), 0)
+    p, states = _fused_args(kernel, lead, m_dim, n_dim)
+    want = _fused(kernel, g, p, states)
+    if layout == "stored_orientation":
+        got = _fused(kernel, jnp.swapaxes(g, -1, -2), p, states,
+                     transposed=True)
+        got = got[:-1] + (jnp.swapaxes(got[-1], -1, -2),)
+    else:
+        got = _fused(kernel, _pad_rows(g, 384), p,
+                     tuple(_pad_rows(x, 384) for x in states))
+        got = tuple(x[..., :m_dim, :] for x in got)
+    assert len(got) == len(want)
+    for i, (a, b) in enumerate(zip(got, want)):
+        assert a.shape == b.shape, (i, a.shape, b.shape)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f"output {i}")
+
+
 def test_coap_fused_update_q8_underflow_clip_guard():
     """The int8-v underflow guard: when V quantizes to all-zero codes while
     M does not, the raw bias-corrected Δ is ~1/eps; the kernel must emit the

@@ -50,29 +50,43 @@ def _tpu_text(fn, sharding, *shapes):
 
 
 F32, I8, I32 = jnp.float32, jnp.int8, jnp.int32
-# LLaMA-1B's projected (m, n) at the paper's rank 512
+# A weight's stored (rows, cols) and the rank. The fused kernels read it
+# as stored: transposed where rows < cols, with a partial last row block
+# where the larger side is not a multiple of the row tile. LLaMA-1B's
+# projected matrices at the paper's rank 512; its up/gate as stored
+# (2048, 5461), transposed and ragged; GLM-4-9B's up/gate as stored
+# (4096, 13696), transposed and ragged, and its down (13696, 4096), ragged.
 FUSED = [(5461, 2048, 512), (2048, 2048, 512)]
+FUSED_BP = FUSED + [(2048, 5461, 512)]
+FUSED_Q8 = FUSED + [(4096, 13696, 512), (13696, 4096, 512)]
 
 
-@pytest.mark.parametrize("m,n,r", FUSED)
-def test_fused_bp_compiles(m, n, r, one_chip, no_persistent_cache):
+def _fused_dims(rows, cols):
+    """(m, n, transposed) of a weight stored (rows, cols)."""
+    return max(rows, cols), min(rows, cols), rows < cols
+
+
+@pytest.mark.parametrize("rows,cols,r", FUSED_BP)
+def test_fused_bp_compiles(rows, cols, r, one_chip, no_persistent_cache):
+    m, n, transposed = _fused_dims(rows, cols)
     text = _tpu_text(
         lambda g, p, mm, v, c: coap_update.coap_fused_update_bp_pallas(
-            g, p, mm, v, c),
-        one_chip, ((m, n), F32), ((n, r), F32), ((m, r), F32),
+            g, p, mm, v, c, transposed=transposed),
+        one_chip, ((rows, cols), F32), ((n, r), F32), ((m, r), F32),
         ((m, r), F32), ((), I32),
     )
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("m,n,r", FUSED)
-def test_fused_q8_compiles(m, n, r, one_chip, no_persistent_cache):
+@pytest.mark.parametrize("rows,cols,r", FUSED_Q8)
+def test_fused_q8_compiles(rows, cols, r, one_chip, no_persistent_cache):
+    m, n, transposed = _fused_dims(rows, cols)
     nb = ref.rowblock_nblocks(r)
     text = _tpu_text(
         lambda g, p, mq, ms, vq, vs, c: quant8.coap_fused_update_q8_pallas(
-            g, p, mq, ms, vq, vs, c),
-        one_chip, ((m, n), F32), ((n, r), F32), ((m, r), I8), ((m, nb), F32),
-        ((m, r), I8), ((m, nb), F32), ((), I32),
+            g, p, mq, ms, vq, vs, c, transposed=transposed),
+        one_chip, ((rows, cols), F32), ((n, r), F32), ((m, r), I8),
+        ((m, nb), F32), ((m, r), I8), ((m, nb), F32), ((), I32),
     )
     assert "tpu_custom_call" in text
 
