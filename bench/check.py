@@ -28,8 +28,11 @@ its own (``bench/limits/<cell>.json``):
   same matrices left out. The norms above average rounding away; this
   one sees it element by element.
 
-A matrix is one layer's slice of a stacked leaf (``stack/...``), or a
-whole leaf elsewhere.
+The model is the reference module the configuration names (its
+``loss``), and so is what a matrix is: ``matrix_axes`` gives how many
+leading axes of a leaf index separate matrices (a stack's layers, an
+expert stack's layers and experts), and a matrix's index counts them in
+row-major order (layer x experts + expert).
 """
 from __future__ import annotations
 
@@ -40,16 +43,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.references import coap_adamw, dense_gqa
+from bench.references import coap_adamw
 
 EXCLUDE_BELOW = 1e-3  # of the median matrix's reference gradient norm
 
 
-def _norms(path: str, x) -> jnp.ndarray:
-    """Per-matrix Frobenius norms of one leaf (layer slices of a stack)."""
+def _norms(path: str, x, matrix_axes) -> jnp.ndarray:
+    """Per-matrix Frobenius norms of one leaf, one for each index of the
+    leading axes that ``matrix_axes`` counts, flattened in row-major order."""
     x = x.astype(jnp.float32)
-    if path.startswith("stack/"):
-        return jnp.sqrt(jnp.sum(jnp.square(x), axis=tuple(range(1, x.ndim))))
+    k = matrix_axes(path, x.shape)
+    if k:
+        return jnp.sqrt(jnp.sum(jnp.square(x), axis=tuple(range(k, x.ndim)))).reshape(-1)
     return jnp.sqrt(jnp.sum(jnp.square(x)))[None]
 
 
@@ -105,12 +110,12 @@ def first_moments(params, opt_state, block: int) -> dict:
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("b1", "block"))
-def program_first_grads(params, opt_state, b1: float, block: int):
+@functools.partial(jax.jit, static_argnames=("b1", "block", "matrix_axes"))
+def program_first_grads(params, opt_state, b1: float, block: int, matrix_axes):
     """(per-matrix norms of every first gradient, the dense ones whole)."""
     ms = first_moments(params, opt_state, block)
     kinds = _dense_paths(params, opt_state)
-    return ({p: _norms(p, m) / (1.0 - b1) for p, m in ms.items()},
+    return ({p: _norms(p, m, matrix_axes) / (1.0 - b1) for p, m in ms.items()},
             {p: m / (1.0 - b1) for p, m in ms.items() if p in kinds})
 
 
@@ -121,20 +126,20 @@ def _dense_paths(params, opt_state) -> set:
             if not hasattr(leaf, "p")}
 
 
-def change_norms(params, initial) -> dict:
+def change_norms(params, initial, matrix_axes) -> dict:
     """{path: per-matrix norms of params - initial} (call under jit)."""
     a, b = _flat(params), _flat(initial)
-    return {p: _norms(p, a[p] - b[p]) for p in a}
+    return {p: _norms(p, a[p] - b[p], matrix_axes) for p in a}
 
 
 # ---------------------------------------------------------- reference side
-def _ref_grads(params, tokens, labels, *, arch, rnd, half_batch, rows):
+def _ref_grads(params, tokens, labels, *, loss, arch, rnd, half_batch, rows):
     """Loss and gradient of the mean over every token, taken ``rows`` rows
     at a time so that a large batch fits."""
     if half_batch:
         tokens, labels = tokens[: tokens.shape[0] // 2], labels[: labels.shape[0] // 2]
     n = tokens.shape[0] // rows if tokens.shape[0] % rows == 0 else 1
-    grad = jax.value_and_grad(dense_gqa.loss)
+    grad = jax.value_and_grad(loss)
     if n == 1:
         return grad(params, tokens, labels, arch, rnd)
 
@@ -166,13 +171,13 @@ def _ref_update(params, grads, state, t, *, refresh, opt, double):
     return new_params, new_state, moved
 
 
-def _grad_norms(grads):
-    return {p: _norms(p, g) for p, g in _flat(grads).items()}
+def _grad_norms(grads, matrix_axes):
+    return {p: _norms(p, g, matrix_axes) for p, g in _flat(grads).items()}
 
 
-def _first_grad(params, state, *, opt):
+def _first_grad(params, state, *, opt, matrix_axes):
     kinds = coap_adamw.leaf_kinds(params, opt)
-    norms = {path: _norms(path, st["m"]) / (1.0 - opt["b1"])
+    norms = {path: _norms(path, st["m"], matrix_axes) / (1.0 - opt["b1"])
              for (path, _, _), st in zip(kinds, state)}
     dense = {path: st["m"] / (1.0 - opt["b1"])
              for (path, _, (kind, _, _)), st in zip(kinds, state) if kind == "dense"}
@@ -180,19 +185,23 @@ def _first_grad(params, state, *, opt):
 
 
 def reference_readings(make_weights, seed: int, batches, arch: dict, opt: dict,
-                       rows: int, rnd=None, half_batch: bool = False,
-                       double: str = "", log=None):
+                       rows: int, model_ref, rnd=None, half_batch: bool = False,
+                       double: bool = False, log=None):
     """Follow ``len(batches)`` steps; returns the readings the gaps compare.
 
     ``batches`` is a list of (tokens, labels) host arrays, one per step;
-    the gradient is taken ``rows`` rows at a time.
-    ``rnd``, ``half_batch`` and ``double`` put the control or a fault in
-    the reference's place (``bench/control.py``)."""
+    the gradient of ``model_ref.loss`` (the configuration's reference
+    module) is taken ``rows`` rows at a time.
+    ``rnd``, ``half_batch`` and ``double`` (``model_ref.DOUBLED`` moves twice)
+    put the control or a fault in the reference's place
+    (``bench/control.py``)."""
     rnd = rnd or (lambda x: x)
     p = functools.partial
+    axes = model_ref.matrix_axes
+    double = model_ref.DOUBLED if double else ""
     with jax.default_matmul_precision("highest"):
-        grad_fn = jax.jit(p(_ref_grads, arch=arch, rnd=rnd, half_batch=half_batch,
-                            rows=rows))
+        grad_fn = jax.jit(p(_ref_grads, loss=model_ref.loss, arch=arch, rnd=rnd,
+                            half_batch=half_batch, rows=rows))
         update = {}  # one compiled step for each set of refreshes
         times = [("start", time.perf_counter())]
         params = make_weights(seed)
@@ -204,7 +213,7 @@ def reference_readings(make_weights, seed: int, batches, arch: dict, opt: dict,
             losses.append(jax.block_until_ready(loss))
             times.append((f"grad{k + 1}", time.perf_counter()))
             if k == 0:
-                extra["grad_norms"] = jax.jit(_grad_norms)(grads)
+                extra["grad_norms"] = jax.jit(p(_grad_norms, matrix_axes=axes))(grads)
             refresh = coap_adamw.refreshes(k, opt)
             if refresh not in update:
                 update[refresh] = jax.jit(p(_ref_update, refresh=refresh, opt=opt,
@@ -214,13 +223,14 @@ def reference_readings(make_weights, seed: int, batches, arch: dict, opt: dict,
             for path, x in jax.device_get(moved).items():
                 extra.setdefault("eqn6_moved", {})[f"{path}@{k + 1}"] = float(x)
             if k == 0:
-                extra["first_grad"], dense = jax.jit(p(_first_grad, opt=opt))(params, state)
+                extra["first_grad"], dense = jax.jit(
+                    p(_first_grad, opt=opt, matrix_axes=axes))(params, state)
                 extra["first_dense"] = jax.device_get(dense)
             del grads
             jax.block_until_ready(params)
             times.append((f"update{k + 1}", time.perf_counter()))
         del state
-        change = jax.jit(change_norms)(params, make_weights(seed))
+        change = jax.jit(p(change_norms, matrix_axes=axes))(params, make_weights(seed))
         out = jax.device_get({"losses": losses, "change": change,
                               **{k: v for k, v in extra.items() if k != "eqn6_moved"}})
         times.append(("norms", time.perf_counter()))
@@ -241,7 +251,7 @@ def _to_lists(d: dict) -> dict:
 
 # ------------------------------------------------------------------ the gaps
 def _gaps_by_matrix(prog: dict, ref: dict, keep=None) -> dict:
-    """{(path, layer): gap} over the kept matrices."""
+    """{(path, matrix index): gap} over the kept matrices."""
     ref_all = [x for p in ref for i, x in enumerate(ref[p]) if keep is None or keep[p][i]]
     median = float(np.median(ref_all))
     return {(p, i): abs(a - b) / max(b, median)
@@ -249,9 +259,10 @@ def _gaps_by_matrix(prog: dict, ref: dict, keep=None) -> dict:
             if keep is None or keep[p][i]}
 
 
-def gaps(program: dict, reference: dict, worst: dict = None) -> dict:
-    """{"loss", "grad", "update"}: the three numbers compared. ``worst``,
-    where given, receives the matrix that sets each of the last two."""
+def gaps(program: dict, reference: dict, matrix_axes, worst: dict = None) -> dict:
+    """{"loss", "grad", "update", "grad_diff"}: the numbers compared, with
+    matrices split by ``matrix_axes``. ``worst``, where given, receives the
+    (path, matrix index) that sets each of the last three."""
     lp, lr = program["losses"], reference["losses"]
     loss = max(abs(a - b) / abs(b) for a, b in zip(lp, lr))
     gnorm = reference["grad_norms"]
@@ -269,8 +280,8 @@ def gaps(program: dict, reference: dict, worst: dict = None) -> dict:
     for p, b in reference["first_dense"].items():
         a = np.asarray(program["first_dense"][p], np.float64)
         b = np.asarray(b, np.float64)
-        if not p.startswith("stack/"):
-            a, b = a[None], b[None]
+        k = matrix_axes(p, b.shape)
+        a, b = a.reshape((-1,) + a.shape[k:]), b.reshape((-1,) + b.shape[k:])
         for i in range(b.shape[0]):
             if keep[p][i]:
                 by[(p, i)] = float(np.linalg.norm(a[i] - b[i])
@@ -283,7 +294,7 @@ def gaps(program: dict, reference: dict, worst: dict = None) -> dict:
 
 
 def excluded(reference: dict) -> list:
-    """Matrices left out of ``update``: [(path, layer)]."""
+    """Matrices left out of ``update``: [(path, matrix index)]."""
     gnorm = reference["grad_norms"]
     median = float(np.median([x for v in gnorm.values() for x in v]))
     return [(p, i) for p, v in gnorm.items() for i, x in enumerate(v)

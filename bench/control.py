@@ -15,8 +15,9 @@ program's place in three ways:
   in;
 * ``half_batch``: each step sees the first half of its rows, the loss the
   mean over them;
-* ``double``: one matrix (every layer's query projection) moves twice as
-  far as the optimizer says, at every step;
+* ``double``: one matrix (``DOUBLED`` of the configuration's reference
+  module: every layer's query projection in a dense decoder) moves twice
+  as far as the optimizer says, at every step;
 * a step that returns its state unchanged reads 1 on ``update`` by
   construction and needs no run.
 
@@ -36,9 +37,6 @@ import jax
 import jax.numpy as jnp
 
 E4M3_MAX = 448.0
-DOUBLED = "stack/attn/wq"
-
-
 E5M2_MAX = 57344.0
 
 
@@ -70,15 +68,15 @@ fp8.defvjp(_fp8_fwd, _fp8_bwd)
 VARIANTS = {
     "control": dict(rnd=fp8),
     "half_batch": dict(half_batch=True),
-    "double": dict(double=DOUBLED),
+    "double": dict(double=True),
 }
 
 
-def _gaps(got, ref):
+def _gaps(got, ref, matrix_axes):
     from bench import check
 
     worst = {}
-    out = check.gaps(got, ref, worst)
+    out = check.gaps(got, ref, matrix_axes, worst)
     out["worst"] = {k: f"{p}[{i}]" for k, (p, i) in worst.items()}
     return out
 
@@ -92,9 +90,10 @@ def readings(runner, variants=VARIANTS, log=None):
     runner.state = None  # the program's state goes before the reference runs
     gc.collect()
     ref = runner.reference(log=log)
-    out = {"program": _gaps(program, ref), "eqn6_moved": ref["eqn6_moved"]}
+    axes = runner.model_ref.matrix_axes
+    out = {"program": _gaps(program, ref, axes), "eqn6_moved": ref["eqn6_moved"]}
     for name, kw in variants.items():
-        out[name] = _gaps(runner.reference(log=log, **kw), ref)
+        out[name] = _gaps(runner.reference(log=log, **kw), ref, axes)
     return out
 
 

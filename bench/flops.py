@@ -1,11 +1,8 @@
 """Operations and bytes, from shapes alone.
 
-``model_flops_per_token`` counts what a training step of a dense decoder
-requires per token: ``6 x`` the parameters that enter a matrix product
-(the embedding lookup is not one; the output head is), plus attention's
-score and value products, ``12 x layers x heads x head_dim x seq`` (the
-PaLM count: the full square, forward and backward). Recomputation is not
-counted.
+What a training step of a model requires per token is the model's own
+count, ``flops_per_token`` of the reference module its configuration
+names (``bench/references/``).
 
 ``fused_update_cost`` counts one call of the fused COAP update on one
 (m, n) matrix at rank r, in its canonical orientation (m >= n): the
@@ -17,22 +14,10 @@ from __future__ import annotations
 from bench.references.coap_adamw import classify
 
 
-def matmul_params(arch: dict) -> int:
-    d, f, n = arch["d_model"], arch["d_ff"], arch["n_layers"]
-    q = arch["n_heads"] * arch["head_dim"]
-    kv = arch["n_kv_heads"] * arch["head_dim"]
-    per_layer = d * q + 2 * d * kv + q * d + 3 * d * f
-    return n * per_layer + d * arch["vocab_size"]
-
-
-def model_flops_per_token(arch: dict, seq: int) -> int:
-    attn = 12 * arch["n_layers"] * arch["n_heads"] * arch["head_dim"] * seq
-    return 6 * matmul_params(arch) + attn
-
-
 def projected_matrices(shapes: dict, opt: dict) -> list:
     """[(path, count, m, n, r)] of the matrices the fused update takes, in
-    canonical orientation; ``count`` is the number of layers stacked."""
+    canonical orientation; ``count`` is the number of matrices the leaf
+    stacks along its leading axes."""
     out = []
     for path in sorted(shapes):
         shape = tuple(shapes[path])

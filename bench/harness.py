@@ -7,7 +7,9 @@ file of its own, found by its name:
   configuration and a traffic mix, and the metrics with the cells that
   report them;
 * ``bench/configs/<config>.json``: the model as run (``file`` in
-  ``BENCHMARK.json``);
+  ``BENCHMARK.json``), naming under ``reference`` the plain reference
+  module that states its parameter tree, loss, operation count and
+  matrix grain (``REFERENCE_NAMES``);
 * ``bench/traffic/<traffic>.json``: optimizer, batch, sequence, steps;
 * ``bench/limits/<cell>.json``: the limit of each number the check
   compares, with the readings it was set from;
@@ -51,9 +53,13 @@ class Cell:
     limits: dict
     end_to_end: list  # metric entries of BENCHMARK.json this cell reports
     per_layer: list
+    reference: Any  # the module the configuration names (reference_module)
 
 
 def _reports(metric: dict, cell: str) -> bool:
+    """A metric with no ``workloads`` list is reported by every cell; one
+    with a list, by the cells it names (a metric a later change adds for
+    its own cells)."""
     return "workloads" not in metric or cell in metric["workloads"]
 
 
@@ -65,14 +71,16 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     conf = next((c for c in spec["configs"] if c["name"] == entry["config"]), None)
     if conf is None:
         raise BenchError(f"cell {name!r}: no configuration {entry['config']!r}")
+    config = _load(root / conf["file"])
     return Cell(
         name=name,
         chips=int(entry["chips"]),
-        config=_load(root / conf["file"]),
+        config=config,
         traffic=_load(root / "bench" / "traffic" / f"{entry['traffic']}.json"),
         limits=_load(root / "bench" / "limits" / f"{name}.json"),
         end_to_end=[m for m in spec["end_to_end"] if _reports(m, name)],
         per_layer=[m for m in spec["per_layer"] if _reports(m, name)],
+        reference=reference_module(config, root),
     )
 
 
@@ -84,15 +92,47 @@ def arch_fields(config: dict) -> dict:
     return fields
 
 
+# What a reference module exports, for the harness to read the model by:
+# ``layout(arch) -> {path: shape}``, the parameter tree the program trains;
+# ``loss(params, tokens, labels, arch, rnd)``, the mean next-token loss;
+# ``flops_per_token(arch, seq)``, what a training step requires a token;
+# ``matrix_axes(path, shape) -> int``, how many leading axes of a leaf
+# index separate matrices; ``DOUBLED``, the path of the matrix that the
+# planted fault of ``bench/control.py`` moves twice.
+REFERENCE_NAMES = ("layout", "loss", "flops_per_token", "matrix_axes", "DOUBLED")
+
+
+def reference_module(config: dict, root: Path = ROOT):
+    """The module a configuration names under ``reference``, a path from
+    the checkout's root, loaded from that file."""
+    rel = config.get("reference")
+    if not rel:
+        raise BenchError(f"configuration {config.get('name')!r} names no reference")
+    path = root / rel
+    if not path.exists():
+        raise BenchError(f"reference {rel} of configuration {config.get('name')!r} "
+                         f"is not at {path}")
+    mod = _module("bench_reference_" + path.stem, path)
+    missing = [n for n in REFERENCE_NAMES if not hasattr(mod, n)]
+    if missing:
+        raise BenchError(f"reference {rel} does not export {missing}")
+    return mod
+
+
 def metric_reader(name: str, root: Path = ROOT):
     path = root / "bench" / "metrics" / f"{name}.py"
     if not path.exists():
         raise BenchError(f"metric {name!r} has no reader at {path}")
+    return _module("bench_metric_" + name, path).read
+
+
+def _module(name: str, path: Path):
+    """The Python file at ``path``, loaded as a module of its own."""
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
 def peaks_for(kind: str, root: Path = ROOT) -> dict:

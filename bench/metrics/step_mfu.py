@@ -1,8 +1,9 @@
 """Model FLOP utilisation of the step on the device, in percent of the
 chip's bf16 peak: the operations a training step requires
-(``bench/flops.py``; no recomputation counted) times the traced steps,
-over the time in which an operation ran on the device in them (the
-trace's busy time), so that host idle is left to ``device_idle_share``."""
+(``flops_per_token`` of the reference module the configuration names; no
+recomputation counted) times the traced steps, over the time in which an
+operation ran on the device in them (the trace's busy time), so that host
+idle is left to ``device_idle_share``."""
 
 
 def read(run):

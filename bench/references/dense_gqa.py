@@ -6,13 +6,17 @@ untied output head, and next-token cross-entropy averaged over every
 token. Every product is a float32 product (``Precision.HIGHEST``).
 
 Nothing here comes from the program under test. Parameters are a nested
-dict of arrays in the layout the benchmark makes them (``bench/weights.py``):
-``embed/embedding`` (V, d); ``stack/...`` with the layer axis first;
-``final_norm`` (d,); ``lm_head/w`` (d, V).
+dict of arrays in the layout below (``layout``), as the benchmark makes
+them (``bench/weights.py``): ``embed/embedding`` (V, d); ``stack/...``
+with the layer axis first; ``final_norm`` (d,); ``lm_head/w`` (d, V).
 
 ``rnd`` rounds each operand of a product before it is taken. The reference
 passes the identity; the precision control passes a rounding to a lower
 precision (``bench/control.py``).
+
+A configuration names this module under ``reference``; the harness reads
+the model only through what it exports (``layout``, ``loss``,
+``flops_per_token``, ``matrix_axes``, ``DOUBLED``; ``PERF.md``, section 4).
 """
 from __future__ import annotations
 
@@ -107,3 +111,55 @@ def loss(params, tokens, labels, arch, rnd=_identity):
     total, _ = jax.lax.scan(jax.checkpoint(head_block), jnp.zeros([], jnp.float32),
                             (rows.reshape(-1, blk, d), ys.reshape(-1, blk)))
     return total / n
+
+
+# ------------------------------------------------- what the harness reads
+DOUBLED = "stack/attn/wq"  # the matrix the planted fault moves twice
+
+
+def layout(arch: dict) -> dict:
+    """{path: shape} of a dense GQA decoder with an untied head."""
+    d, f, v, n = arch["d_model"], arch["d_ff"], arch["vocab_size"], arch["n_layers"]
+    q, kv = arch["n_heads"] * arch["head_dim"], arch["n_kv_heads"] * arch["head_dim"]
+    shapes = {
+        "embed/embedding": (v, d),
+        "final_norm": (d,),
+        "lm_head/w": (d, v),
+        "stack/attn/wq": (n, d, q),
+        "stack/attn/wk": (n, d, kv),
+        "stack/attn/wv": (n, d, kv),
+        "stack/attn/wo": (n, q, d),
+        "stack/ln1": (n, d),
+        "stack/ln2": (n, d),
+        "stack/mlp/gate": (n, d, f),
+        "stack/mlp/up": (n, d, f),
+        "stack/mlp/down": (n, f, d),
+    }
+    if arch.get("qkv_bias"):
+        shapes.update({"stack/attn/wq_bias": (n, q), "stack/attn/wk_bias": (n, kv),
+                       "stack/attn/wv_bias": (n, kv)})
+    return shapes
+
+
+def matrix_axes(path: str, shape) -> int:
+    """How many leading axes of a leaf index separate matrices: the layer
+    axis of a ``stack/`` leaf; none elsewhere."""
+    return 1 if path.startswith("stack/") else 0
+
+
+def matmul_params(arch: dict) -> int:
+    d, f, n = arch["d_model"], arch["d_ff"], arch["n_layers"]
+    q = arch["n_heads"] * arch["head_dim"]
+    kv = arch["n_kv_heads"] * arch["head_dim"]
+    per_layer = d * q + 2 * d * kv + q * d + 3 * d * f
+    return n * per_layer + d * arch["vocab_size"]
+
+
+def flops_per_token(arch: dict, seq: int) -> int:
+    """What a training step requires per token: ``6 x`` the parameters
+    that enter a matrix product (the embedding lookup is not one; the
+    output head is), plus attention's score and value products, ``12 x
+    layers x heads x head_dim x seq`` (the PaLM count: the full square,
+    forward and backward). Recomputation is not counted."""
+    attn = 12 * arch["n_layers"] * arch["n_heads"] * arch["head_dim"] * seq
+    return 6 * matmul_params(arch) + attn

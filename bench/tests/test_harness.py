@@ -1,6 +1,7 @@
 """The harness on the CPU: it refuses the CPU, finds a cell it is given as
-files alone, and a run comes out correct, and not correct with the timed
-path broken underneath it."""
+files alone, reads the model through the reference module its
+configuration names, and a run comes out correct, and not correct with
+the timed path broken underneath it."""
 import json
 
 import pytest
@@ -47,8 +48,27 @@ def test_a_cell_added_as_files_is_found(root):
     assert harness.arch_fields(cell.config)["d_model"] == 64
     # the metrics that list no cells are reported by the new cell too
     assert {m["name"] for m in cell.end_to_end} == {
-        "tokens_per_s", "peak_hbm_gib", "setup_s"}
-    assert "eqn6_unfused_buckets" not in {m["name"] for m in cell.per_layer}
+        "tokens_per_s", "step_ms_p90", "peak_hbm_gib", "setup_s"}
+    assert {"eqn6_unfused_buckets", "opt_update_ms", "bucket_copy_ms",
+            "refresh_ms"} <= {m["name"] for m in cell.per_layer}
+    assert cell.reference.__file__ == str(root / cell.config["reference"])
+
+
+@pytest.mark.parametrize("group", ["end_to_end", "per_layer"])
+def test_a_metric_that_lists_its_cells_is_reported_by_those_alone(tmp_path, group):
+    root = tiny.make_root(tmp_path)
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    other = next(w["name"] for w in spec["workloads"] if w["name"] != tiny.CELL)
+    listed = dict(spec[group][0], name="listed_elsewhere", workloads=[other])
+    spec[group].append(listed)
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+
+    def names(cell):
+        return {m["name"] for m in getattr(harness.load_cell(cell, root), group)}
+
+    assert "listed_elsewhere" in names(other)
+    assert "listed_elsewhere" not in names(tiny.CELL)
+    assert spec[group][0]["name"] in names(tiny.CELL)
 
 
 def test_run_is_correct(root):
@@ -56,7 +76,8 @@ def test_run_is_correct(root):
     assert result["correct"], result["check"]
     assert list(result)[-1] == "check"
     assert result["attempted"] >= 1 and result["failed"] == 0
-    assert set(result["metrics"]) == {"tokens_per_s", "peak_hbm_gib", "setup_s"}
+    assert set(result["metrics"]) == {"tokens_per_s", "step_ms_p90", "peak_hbm_gib",
+                                      "setup_s"}
 
 
 def _unchanged_state(monkeypatch):
@@ -141,3 +162,52 @@ def test_refuses_a_second_state_on_the_device(root, monkeypatch):
     monkeypatch.setattr(train_cell.TrainCell, "batch_fn", keeps_state)
     with pytest.raises(RuntimeError, match="live on the device"):
         tiny.run(root)
+
+
+# A configuration brings its model as files: the module its ``reference``
+# names, written into the checkout beside it.
+REEXPORT = "from bench.references.dense_gqa import *  # noqa: F401,F403\n"
+
+
+def test_a_reference_named_by_the_configuration_runs_correct(tmp_path):
+    root = tiny.make_root(tmp_path, reference=REEXPORT)
+    cell = harness.load_cell(tiny.CELL, root)
+    assert cell.config["reference"] == tiny.PROBE
+    assert cell.reference.__file__ == str(root / tiny.PROBE)
+    result = tiny.run(root)
+    assert result["correct"], result["check"]
+
+
+def test_the_named_reference_loss_decides(tmp_path):
+    scaled = REEXPORT + (
+        "from bench.references import dense_gqa as _dense\n\n\n"
+        "def loss(params, tokens, labels, arch, rnd=_dense._identity):\n"
+        "    return 1.5 * _dense.loss(params, tokens, labels, arch, rnd)\n")
+    result = tiny.run(tiny.make_root(tmp_path, reference=scaled))
+    check = result["check"]
+    assert not result["correct"], check
+    # the first gradient is clipped to norm 1 on both sides: only the loss
+    # can tell the scaled reference from the program
+    assert check["loss"]["value"] > check["loss"]["limit"], check
+
+
+def test_refuses_a_reference_whose_layout_differs_from_the_program(tmp_path):
+    short = REEXPORT + (
+        "from bench.references import dense_gqa as _dense\n\n\n"
+        "def layout(arch):\n"
+        "    shapes = _dense.layout(arch)\n"
+        "    del shapes['final_norm']\n"
+        "    return shapes\n")
+    root = tiny.make_root(tmp_path, reference=short)
+    with pytest.raises(harness.BenchError, match=f"layout of {tiny.PROBE}"):
+        tiny.run(root)
+
+
+@pytest.mark.parametrize("conf, match", [
+    ({"name": "x"}, "names no reference"),
+    ({"name": "x", "reference": "bench/references/absent.py"}, "is not at"),
+    ({"name": "x", "reference": "bench/references/coap_adamw.py"}, "does not export"),
+], ids=["unnamed", "absent", "no_interface"])
+def test_refuses_a_reference_it_cannot_read(conf, match):
+    with pytest.raises(harness.BenchError, match=match):
+        harness.reference_module(conf)

@@ -34,7 +34,7 @@ def test_model_loss_and_grads_agree(arch_name):
 
     cfg, arch = _smoke(arch_name)
     model = build_model(cfg)
-    params = weights.maker(weights.dense_gqa_layout(arch))(2 ** 33 + 5)
+    params = weights.maker(dense_gqa.layout(arch))(2 ** 33 + 5)
     if arch["qkv_bias"]:  # zero biases would hide a missing bias
         params["stack"]["attn"] = {
             k: (v + 0.1 if k.endswith("bias") else v)
@@ -69,7 +69,7 @@ def test_optimizer_steps_agree(name):
     tx = make_optimizer(OptimizerConfig(
         name=name, learning_rate=opt["lr"], rank=opt["rank"], min_dim=opt["min_dim"],
         t_update=opt["t_update"], lam=opt["lam"], seed=opt["opt_seed"]))
-    layout = weights.dense_gqa_layout(arch)
+    layout = dense_gqa.layout(arch)
     opt["phases"] = tiny.program_phases(layout, opt)
     params = weights.maker(layout)(3)
     kinds = [k for _, _, (k, _, _) in coap_adamw.leaf_kinds(params, opt)]

@@ -186,7 +186,7 @@ def run(recorded, monkeypatch):
     monkeypatch.setattr(scopes, "step_hlo", lambda run: text)
     cell = harness.Cell(name="toy", chips=1, config={},
                         traffic={"optimizer": meta["optimizer"]}, limits={},
-                        end_to_end=[], per_layer=[])
+                        end_to_end=[], per_layer=[], reference=None)
     out = harness.Run(cell=cell, peaks={}, trace=reduced,
                       traced_steps=meta["traced_steps"],
                       shapes={p: tuple(s) for p, s in meta["shapes"].items()})
@@ -227,8 +227,8 @@ def test_the_loop_that_ran_is_the_harness_frames():
     other = harness.Run(cell=None, peaks={})
 
     def run_cell(run):  # as the harness holds them when it calls a reader
-        runner = TrainCell(harness.Cell("toy", 1, {}, {"optimizer": {}}, {}, [], []),
-                           {}, 0)
+        cell = harness.Cell("toy", 1, {}, {"optimizer": {}}, {}, [], [], None)
+        runner = TrainCell(cell, {}, 0)
         runner.loop = object()
         return runner, scopes.cell_that_ran(run), scopes.cell_that_ran(other)
 

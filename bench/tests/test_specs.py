@@ -7,7 +7,7 @@ import re
 import jax
 import pytest
 
-from bench import harness, weights
+from bench import harness
 from bench.references import coap_adamw
 
 SPEC = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
@@ -74,10 +74,25 @@ def test_config_resolves_against_the_registry(conf):
     cfg = dataclasses.replace(get_config(data["registry_arch"]), **fields)
     for k, v in fields.items():
         assert getattr(cfg, k) == v
-    assert cfg.resolved_head_dim == fields["head_dim"]
     got = {coap_adamw.path_of(kp): tuple(x.shape) for kp, x in
            jax.tree_util.tree_flatten_with_path(build_model(cfg).abstract_params())[0]}
-    assert got == weights.dense_gqa_layout(fields)
+    assert got == harness.reference_module(data).layout(fields)
+
+
+DENSE = [c for c in SPEC["configs"] if json.loads((harness.ROOT / c["file"]).read_text())
+         ["reference"] == "bench/references/dense_gqa.py"]
+
+
+@pytest.mark.parametrize("conf", DENSE, ids=lambda c: c["name"])
+def test_dense_config_states_the_programs_head_dim(conf):
+    """The dense reference sizes its heads by ``head_dim``; the program
+    resolves the same."""
+    from repro.configs import get_config
+
+    data = json.loads((harness.ROOT / conf["file"]).read_text())
+    fields = harness.arch_fields(data)
+    cfg = dataclasses.replace(get_config(data["registry_arch"]), **fields)
+    assert cfg.resolved_head_dim == fields["head_dim"]
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -88,7 +103,7 @@ def test_stated_phases_are_the_programs(cell):
 
     c = harness.load_cell(cell)
     o = c.traffic["optimizer"]
-    shapes = weights.dense_gqa_layout(harness.arch_fields(c.config))
+    shapes = c.reference.layout(harness.arch_fields(c.config))
     assert o["phases"] == tiny.program_phases(shapes, o)
 
 

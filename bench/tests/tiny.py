@@ -1,14 +1,18 @@
 """A tiny cell for the CPU tests, added to a temporary copy of the
 benchmark's files the way a later change adds one: a configuration file,
-a traffic file, a limits file and two entries of ``BENCHMARK.json``."""
+a traffic file, a limits file and two entries of ``BENCHMARK.json``, and,
+where a test gives one, the reference module the configuration names."""
 from __future__ import annotations
 
 import copy
+import gc
 import json
 import shutil
 from pathlib import Path
 
 from bench import harness, weights
+
+PROBE = "bench/references/probe.py"
 
 CELL = "tiny.coap"
 # Set from CPU readings at this size over 8 compared steps, seven seeds
@@ -46,8 +50,11 @@ def program_phases(shapes: dict, opt: dict) -> dict:
     return out
 
 
-def make_root(tmp: Path, optimizer: str = "coap-adamw", limits=None) -> Path:
-    """A copy of BENCHMARK.json and bench/'s data with the tiny cell added."""
+def make_root(tmp: Path, optimizer: str = "coap-adamw", limits=None,
+              reference: str = None) -> Path:
+    """A copy of BENCHMARK.json and bench/'s data with the tiny cell added.
+    ``reference``, where given, is the source of a module written to
+    ``PROBE`` in the copy, which the tiny configuration then names."""
     src = harness.ROOT
     root = tmp / "checkout"
     shutil.copytree(src / "bench", root / "bench",
@@ -64,7 +71,11 @@ def make_root(tmp: Path, optimizer: str = "coap-adamw", limits=None) -> Path:
     traffic["optimizer"].update(name=optimizer, rank=16, min_dim=32,
                                 quantize=optimizer.startswith("8bit-"))
     traffic["optimizer"]["phases"] = program_phases(
-        weights.dense_gqa_layout(harness.arch_fields(conf)), traffic["optimizer"])
+        harness.reference_module(conf).layout(harness.arch_fields(conf)),
+        traffic["optimizer"])
+    if reference is not None:
+        (root / PROBE).write_text(reference)
+        conf["reference"] = PROBE
     (root / "bench/configs/tiny.json").write_text(json.dumps(conf))
     (root / "bench/traffic/tiny.json").write_text(json.dumps(traffic))
     (root / f"bench/limits/{CELL}.json").write_text(json.dumps(limits or LIMITS))
@@ -82,7 +93,11 @@ CPU_PEAKS = {"bf16_flop_per_s": 1e12, "hbm_bytes_per_s": 1e11}
 
 
 def run(root: Path, seed: int = 2 ** 31 + 7, seconds: float = 0.5) -> dict:
-    """Drive one run of the tiny cell on the CPU, skipping the chip check."""
+    """Drive one run of the tiny cell on the CPU, skipping the chip check.
+    What an earlier test left to the garbage collector (a refused run's
+    state, held in a traceback's cycle) goes first, so that the check of
+    one state on the device counts this run's arrays alone."""
+    gc.collect()
     cell = harness.load_cell(CELL, root)
     return harness.run_cell(cell, seed, seconds, trace=False, t0=0.0,
                             device=dict(CPU), peaks=dict(CPU_PEAKS),

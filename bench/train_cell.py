@@ -14,6 +14,7 @@ takes batch ``i mod distinct_steps``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import math
 import shutil
@@ -24,7 +25,8 @@ import time
 import jax
 
 from bench import check as chk
-from bench import flops, markov, weights
+from bench import markov, weights
+from bench.harness import BenchError
 
 
 def _profile_options():
@@ -38,6 +40,7 @@ def _profile_options():
 class TrainCell:
     def __init__(self, cell, arch: dict, seed: int, log=print):
         self.cell = cell
+        self.model_ref = cell.reference  # the configuration's reference module
         self.arch = arch
         self.seed = seed
         self.log = log
@@ -114,12 +117,13 @@ class TrainCell:
         t = self.traffic
         if not hasattr(self, "loop"):
             model, self.tx = self._build()
-            self.layout = weights.dense_gqa_layout(self.arch)
+            self.layout = self.model_ref.layout(self.arch)
             want = {chk.coap_adamw.path_of(kp): tuple(x.shape) for kp, x in
                     jax.tree_util.tree_flatten_with_path(model.abstract_params())[0]}
             if want != {p: tuple(s) for p, s in self.layout.items()}:
-                raise ValueError(f"the program's parameters {want} differ from "
-                                 f"the benchmark's layout {self.layout}")
+                raise BenchError(f"the program's parameters {want} differ from the "
+                                 f"layout of {self.cell.config['reference']}: "
+                                 f"{self.layout}")
             self.make_weights = weights.maker(self.layout)
             self.loop = TrainLoop(model, self.tx, self.batch_fn,
                                   TrainLoopConfig(total_steps=0,
@@ -138,13 +142,16 @@ class TrainCell:
                 self._check_one_state()
                 norms, dense = jax.device_get(chk.program_first_grads(
                     self.state.params, self.state.opt_state, b1=self.opt["b1"],
-                    block=self.opt["quant_block"]))
+                    block=self.opt["quant_block"],
+                    matrix_axes=self.model_ref.matrix_axes))
                 self.readings["first_grad"] = chk._to_lists(norms)
                 self.readings["first_dense"] = dense
         hist = {int(r["step"]): r["loss"] for r in self.loop.logger.history}
         self.readings["losses"] = [hist[s] for s in range(k)]
+        change = jax.jit(functools.partial(chk.change_norms,
+                                           matrix_axes=self.model_ref.matrix_axes))
         self.readings["change"] = chk._to_lists(jax.device_get(
-            jax.jit(chk.change_norms)(self.state.params, self.make_weights(self.seed))))
+            change(self.state.params, self.make_weights(self.seed))))
         if not warmup:
             return
         self._run_to(t["warmup_steps"])
@@ -171,7 +178,7 @@ class TrainCell:
 
         t = self.traffic
         run.tokens_per_step = t["batch"] * t["grad_accum"] * t["seq"]
-        run.flops_per_step = run.tokens_per_step * flops.model_flops_per_token(
+        run.flops_per_step = run.tokens_per_step * self.model_ref.flops_per_token(
             self.arch, t["seq"])
         run.shapes = dict(self.layout)
         traced = min(t["trace_steps"], max(1, math.ceil(seconds / self.est_step_s))) if trace else 0
@@ -241,13 +248,13 @@ class TrainCell:
         puts the control or a fault in its place (``bench/control.py``)."""
         return chk.reference_readings(self.make_weights, self.seed, self.compared,
                                       self.arch, self.opt, self.traffic["batch"],
-                                      log=log, **fault)
+                                      self.model_ref, log=log, **fault)
 
     def check(self, log=print) -> dict:
         t0 = time.perf_counter()
         ref = self.reference(log=log)
         worst = {}
-        numbers = chk.gaps(self.readings, ref, worst)
+        numbers = chk.gaps(self.readings, ref, self.model_ref.matrix_axes, worst)
         log(f"[check] reference {time.perf_counter() - t0:.3f} s; losses "
             f"program {self.readings['losses']} reference {ref['losses']}; "
             f"Eqn 6 moved P by (norm of the change over the norm) {ref['eqn6_moved']}; "

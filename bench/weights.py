@@ -1,8 +1,9 @@
 """Weights of a cell, made on the device from the seed in one jitted call.
 
-The layout is the benchmark's own statement of the parameter tree the
-program trains (paths and shapes); the harness refuses to run where the
-program's tree differs. Initialisation, by path:
+The layout is the parameter tree the program trains (paths and shapes),
+as ``layout(arch)`` of the reference module the configuration names
+states it; the harness refuses to run where the program's tree differs.
+Initialisation, by path:
 
 * ``embed/embedding``: normal, standard deviation 0.02;
 * norms (``ln1``, ``ln2``, ``final_norm``): ones; biases: zeros;
@@ -20,30 +21,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-
-
-def dense_gqa_layout(arch: dict) -> dict:
-    """{path: shape} of a dense GQA decoder with an untied head."""
-    d, f, v, n = arch["d_model"], arch["d_ff"], arch["vocab_size"], arch["n_layers"]
-    q, kv = arch["n_heads"] * arch["head_dim"], arch["n_kv_heads"] * arch["head_dim"]
-    shapes = {
-        "embed/embedding": (v, d),
-        "final_norm": (d,),
-        "lm_head/w": (d, v),
-        "stack/attn/wq": (n, d, q),
-        "stack/attn/wk": (n, d, kv),
-        "stack/attn/wv": (n, d, kv),
-        "stack/attn/wo": (n, q, d),
-        "stack/ln1": (n, d),
-        "stack/ln2": (n, d),
-        "stack/mlp/gate": (n, d, f),
-        "stack/mlp/up": (n, d, f),
-        "stack/mlp/down": (n, f, d),
-    }
-    if arch.get("qkv_bias"):
-        shapes.update({"stack/attn/wq_bias": (n, q), "stack/attn/wk_bias": (n, kv),
-                       "stack/attn/wv_bias": (n, kv)})
-    return shapes
 
 
 def _init_leaf(key, path: str, shape):
